@@ -26,36 +26,29 @@ wall-clock and gate only the full run; ``--tiny`` (CI smoke on forced host
 devices) keeps the structural checks — gaps recorded, every request
 finished, KV actually crossed the channel.
 
-Needs two devices, so direct runs force
-``--xla_force_host_platform_device_count=2`` before importing jax, and the
-harness entry (``benchmarks.run``) re-executes this module in a subprocess
-(the parent's jax is already initialized with one device).
+Needs two devices and runs in the calling process, which holds them.  On
+the CPU, direct runs and the harness (``benchmarks.run``) force
+``--xla_force_host_platform_device_count=2`` before JAX starts; on an
+accelerator with fewer than two devices the run raises.
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=2 \
-        PYTHONPATH=src python -m benchmarks.disagg_interference [--tiny]
+    PYTHONPATH=src python -m benchmarks.disagg_interference [--tiny]
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import subprocess
 import sys
 import time
-from pathlib import Path
 
 import numpy as np
 
 from .common import (LATENCY_COLUMNS, add_trace_arg, finish_trace,
                      latency_rows, markdown_table, save_result, start_trace)
 
-REPO = Path(__file__).resolve().parent.parent
-MARKER = "DISAGG_INTERFERENCE_JSON:"
 
-
-def _ensure_devices(n: int = 2) -> None:
-    """Force ``n`` host devices — only effective before jax first imports,
-    which is why ``run()`` goes through a subprocess."""
+def ensure_host_devices(n: int = 2) -> None:
+    """Force ``n`` CPU host devices.  Effective only before JAX starts its
+    backends, and only on the CPU backend."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = \
@@ -140,6 +133,13 @@ def _measure(tiny: bool) -> dict:
     knobs = dict(n_slots=n_dec + 1, max_len=max_len, prompt_len=long_len,
                  prefill_chunk=chunk)
 
+    devices = jax.devices()
+    if len(devices) < 2:
+        raise RuntimeError(
+            f"disagg_interference needs two devices for its two pools; this "
+            f"process has {len(devices)} {devices[0].platform} device(s).  "
+            "On the CPU, start it with "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=2.")
     pmesh, dmesh = make_disagg_meshes()
     engines = {
         "colocated": EngineCore(cfg, params, **knobs),
@@ -243,41 +243,28 @@ def _measure(tiny: bool) -> dict:
 
 
 def run(tiny: bool = False) -> dict:
-    """Harness entry: the parent process's jax is already pinned to one
-    device, so the measurement re-executes this module in a subprocess with
-    the forced-device flag and parses its JSON marker line."""
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count=2 {flags}".strip()
-    src = str(REPO / "src")
-    env["PYTHONPATH"] = src + os.pathsep * bool(env.get("PYTHONPATH", "")) \
-        + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, "-m", "benchmarks.disagg_interference", "--emit-json"]
-    if tiny:
-        cmd.append("--tiny")
-    out = subprocess.run(cmd, capture_output=True, text=True, timeout=1200,
-                         env=env, cwd=REPO)
-    for line in out.stdout.splitlines():
-        if line.startswith(MARKER):
-            result = json.loads(line[len(MARKER):])
-            save_result(result)
-            return result
-    raise RuntimeError(
-        f"disagg_interference subprocess produced no result marker\n"
-        f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}")
+    """Harness entry: measures in this process, on its devices, and puts
+    back the process-wide dispatch settings ``_measure`` changes."""
+    import jax
+
+    interval = sys.getswitchinterval()
+    async_dispatch = jax.config.read("jax_cpu_enable_async_dispatch")
+    try:
+        result = _measure(tiny=tiny)
+    finally:
+        sys.setswitchinterval(interval)
+        jax.config.update("jax_cpu_enable_async_dispatch", async_dispatch)
+    save_result(result)
+    return result
 
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--tiny", action="store_true",
                    help="CI smoke: small model/workload, structural checks only")
-    p.add_argument("--emit-json", action="store_true",
-                   help="print the machine-readable result marker (harness)")
     add_trace_arg(p)
     args = p.parse_args(argv)
-    _ensure_devices(2)
+    ensure_host_devices(2)
     start_trace(args.trace_out)
     result = _measure(tiny=args.tiny)
     finish_trace(args.trace_out)
@@ -288,8 +275,6 @@ def main(argv=None) -> int:
     print(markdown_table(result["latency_rows"], list(LATENCY_COLUMNS)))
     print()
     print(result["notes"])
-    if args.emit_json:
-        print(MARKER + json.dumps(result, default=float))
     return 0 if all(result["checks"].values()) else 1
 
 

@@ -31,6 +31,7 @@ from . import (
     traffic_storm,
 )
 from .common import render
+from repro.common.compile_cache import enable_compile_cache
 
 BENCHES = {
     "roofline_report": roofline_report,
@@ -58,6 +59,10 @@ def main(argv=None) -> int:
     p.add_argument("--only", nargs="*", choices=list(BENCHES), default=None)
     args = p.parse_args(argv)
     names = args.only or list(BENCHES)
+    # before JAX starts its backends: disagg_interference runs in this
+    # process and needs two devices (the flag only affects the CPU backend)
+    disagg_interference.ensure_host_devices(2)
+    enable_compile_cache()
 
     failures, all_checks = [], []
     for name in names:

@@ -15,7 +15,11 @@ three hand-maintained invariants that, until now, only review enforced:
 3. **fp cache never exists in HBM** (PR 3) — the quantized variants'
    jaxprs must not allocate an fp32 intermediate as large as the
    dequantized KV cache: dequant happens per-tile in VMEM inside the
-   kernel, never as a whole-cache materialization feeding it.
+   kernel, never as a whole-cache materialization feeding it;
+4. **TPU block tiling** — Mosaic refuses a block whose last two dims are
+   not divisible by (8, 128) and not equal to the array's dims (rank 1: a
+   multiple of 128 x the dtype's packing, or the whole array).  Interpret
+   mode accepts such blocks, so only a compile for the chip found them.
 
 Mechanism: ``pl.pallas_call`` is monkeypatched to a recorder that captures
 (grid, specs, operands) and returns zeros of ``out_shape``; each op entry
@@ -66,6 +70,8 @@ class _Captured:
     nsp: int
     operand_shapes: List[Tuple[int, ...]]
     scalars: List[Any]  # concrete np arrays (or None when traced)
+    # (shape, dtype) of every array a spec tiles: block operands, then outputs
+    tiled: List[Tuple[Tuple[int, ...], Any]] = dataclasses.field(default_factory=list)
 
 
 def _as_list(x) -> list:
@@ -103,7 +109,9 @@ def _recorder(captured: List[_Captured]):
             captured.append(_Captured(
                 grid=g, in_specs=ins, out_specs=outs, nsp=nsp,
                 operand_shapes=[tuple(x.shape) for x in operands],
-                scalars=scalars))
+                scalars=scalars,
+                tiled=[(tuple(x.shape), x.dtype) for x in operands[nsp:]]
+                + [(tuple(s.shape), s.dtype) for s in shapes]))
             res = [jnp.zeros(s.shape, s.dtype) for s in shapes]
             return res if isinstance(out_shape, (list, tuple)) else res[0]
 
@@ -128,6 +136,18 @@ def _block_shape(spec) -> Optional[Tuple]:
 
 def _index_map(spec) -> Optional[Callable]:
     return getattr(spec, "index_map", None)
+
+
+def tpu_tiling_ok(block: Sequence, shape: Sequence[int], dtype) -> bool:
+    """Mosaic's block-tiling rule (squeezed ``None`` dims count as 1)."""
+    import numpy as np
+
+    dims = [1 if b is None else int(b) for b in block]
+    if len(dims) == 1:
+        tile = 128 * max(1, 4 // np.dtype(dtype).itemsize)
+        return dims[0] == shape[0] or dims[0] % tile == 0
+    return ((dims[-1] == shape[-1] or dims[-1] % 128 == 0)
+            and (dims[-2] == shape[-2] or dims[-2] % 8 == 0))
 
 
 def _check_captured(cap: _Captured, where: Tuple[str, int], label: str,
@@ -162,6 +182,18 @@ def _check_captured(cap: _Captured, where: Tuple[str, int], label: str,
                         f"{label}: in_spec[{si}] block dim {d} = {b} does "
                         f"not divide operand dim {operand_shape[d]} — the "
                         f"op must pad before tiling"))
+    for si, spec in enumerate(specs):
+        block = _block_shape(spec)
+        if block is None or si >= len(cap.tiled):
+            continue
+        shape, dtype = cap.tiled[si]
+        if len(block) == len(shape) and not tpu_tiling_ok(block, shape, dtype):
+            kind = f"in_spec[{si}]" if si < n_in else f"out_spec[{si - n_in}]"
+            findings.append(Finding(
+                PASS, "kernel:block-tiling", rel, line,
+                f"{label}: {kind} block {tuple(block)} over array {shape} "
+                f"breaks the TPU tiling rule: the last two block dims must "
+                f"be divisible by (8, 128) or equal the array's dims"))
     # index-map bounds (needs concrete scalars; skipped under tracing)
     if any(s is None for s in cap.scalars):
         return

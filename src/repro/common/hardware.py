@@ -1,9 +1,8 @@
 """Hardware model of the target platform (TPU v5e) and of the paper's platform.
 
 All roofline math in :mod:`repro.core.roofline` and the DSE in
-:mod:`repro.core.dse` reads these constants.  The container we develop in is
-CPU-only; v5e is the *target*, exactly like the paper's Vitis flow targets the
-KV260 from an x86 host.
+:mod:`repro.core.dse` reads these constants; a run on a chip looks its peaks
+up by ``device_kind`` (:func:`chip_for_kind`).
 """
 from __future__ import annotations
 
@@ -30,6 +29,8 @@ class ChipSpec:
     dcn_bw: float
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 394 TOP/s int8,
+# 16 GB of HBM at 819 GB/s.
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -48,6 +49,22 @@ KV260_DDR_BW = 19.2e9  # bytes/s, theoretical LPDDR4 peak used in the paper's re
 KV260_POWER_W = 4.9  # PD-Swap's measured power (Table 1)
 
 DEFAULT_CHIP = TPU_V5E
+
+# ``jax.Device.device_kind`` -> the chip's peaks.
+CHIPS_BY_KIND = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for_kind(device_kind: str) -> ChipSpec:
+    """The peaks of a device as JAX names it; an unknown kind is an error,
+    never a default chip."""
+    try:
+        return CHIPS_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks known for device kind {device_kind!r}; add it to "
+            f"CHIPS_BY_KIND ({sorted(CHIPS_BY_KIND)})") from None
 
 
 def mesh_chips(mesh_shape: tuple[int, ...]) -> int:

@@ -87,7 +87,7 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
 
     # --- execution knobs (static: part of the jit cache key) ---
-    # Dispatch Pallas kernels (interpret=True on CPU; compiled on TPU).
+    # Dispatch Pallas kernels (compiled on a TPU, interpreted on the CPU).
     use_pallas: bool = False
     # Attention-core implementation for lowering:
     #   "xla"  — generic jnp/XLA attention (the static-baseline program)

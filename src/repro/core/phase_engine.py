@@ -408,14 +408,7 @@ class PhaseEngine:
         if self.mesh is not None:
             psh = self.param_shardings(params_abstract)
             tok_sh = self._sd(pctx, "batch")
-            if self.kv_dtype != "fp":
-                from repro.models import transformer as T
-
-                cache_abstract = jax.eval_shape(
-                    lambda: T.init_cache(cfg, batch, max_len, kv_dtype=self.kv_dtype))
-            else:
-                cache_abstract = jax.eval_shape(lambda: api.init_cache(cfg, batch, max_len))
-            cache_sh = self._cache_shardings(cache_abstract)
+            cache_sh = self.cache_shardings(batch, max_len)
             in_sh = (psh, tok_sh, cache_sh, self._sd(pctx, "batch"))
         return self._program(key, fn, in_shardings=in_sh, donate=(2,),
                              phase="decode")
@@ -437,18 +430,7 @@ class PhaseEngine:
         in_sh = None
         if self.mesh is not None:
             psh = self.param_shardings(params_abstract)
-            # Pages shard over heads/head_dim; the page axis stays replicated
-            # (any sequence's table may reference any page).
-            page_sh = self._sd(pctx, None, "layers", "kv_heads", None, "head_dim")
-            from repro.layers.attention import KVCache
-            if self.kv_dtype != "fp":
-                from repro.quant.kv_quant import QuantKV
-
-                scale_sh = self._sd(pctx, None, "layers", "kv_heads", None)
-                leaf_sh = QuantKV(page_sh, scale_sh)
-            else:
-                leaf_sh = page_sh
-            in_sh = (psh, self._sd(pctx, "batch"), KVCache(leaf_sh, leaf_sh), None,
+            in_sh = (psh, self._sd(pctx, "batch"), self.page_pool_shardings(), None,
                      self._sd(pctx, "batch"))
         return self._program(key, fn, in_shardings=in_sh, donate=(2,),
                              phase="decode")
@@ -478,12 +460,7 @@ class PhaseEngine:
         in_sh = None
         if self.mesh is not None:
             psh = self.param_shardings(params_abstract)
-            if self.kv_dtype != "fp":
-                cache_abstract = jax.eval_shape(
-                    lambda: T.init_cache(cfg, batch, max_len, kv_dtype=self.kv_dtype))
-            else:
-                cache_abstract = jax.eval_shape(lambda: self.api.init_cache(cfg, batch, max_len))
-            in_sh = (psh, self._sd(pctx, "batch", None), self._cache_shardings(cache_abstract),
+            in_sh = (psh, self._sd(pctx, "batch", None), self.cache_shardings(batch, max_len),
                      self._sd(pctx, "batch"), self._sd(pctx, "batch"))
         return self._program(key, fn, in_shardings=in_sh, donate=(2,),
                              phase="decode")
@@ -506,16 +483,7 @@ class PhaseEngine:
         in_sh = None
         if self.mesh is not None:
             psh = self.param_shardings(params_abstract)
-            page_sh = self._sd(pctx, None, "layers", "kv_heads", None, "head_dim")
-            from repro.layers.attention import KVCache
-            if self.kv_dtype != "fp":
-                from repro.quant.kv_quant import QuantKV
-
-                scale_sh = self._sd(pctx, None, "layers", "kv_heads", None)
-                leaf_sh = QuantKV(page_sh, scale_sh)
-            else:
-                leaf_sh = page_sh
-            in_sh = (psh, self._sd(pctx, "batch", None), KVCache(leaf_sh, leaf_sh), None,
+            in_sh = (psh, self._sd(pctx, "batch", None), self.page_pool_shardings(), None,
                      self._sd(pctx, "batch"), self._sd(pctx, "batch"))
         return self._program(key, fn, in_shardings=in_sh, donate=(2,),
                              phase="decode")
@@ -576,6 +544,39 @@ class PhaseEngine:
             )
 
         return self._program(key, fn, donate=(0,), phase="swap")
+
+    def cache_shardings(self, batch: int, max_len: int):
+        """Shardings of the decode-layout cache that the decode and verify
+        programs take (None without a mesh)."""
+        if self.mesh is None:
+            return None
+        if self.kv_dtype != "fp":
+            from repro.models import transformer as T
+
+            cache_abstract = jax.eval_shape(
+                lambda: T.init_cache(self.cfg, batch, max_len, kv_dtype=self.kv_dtype))
+        else:
+            cache_abstract = jax.eval_shape(lambda: self.api.init_cache(self.cfg, batch, max_len))
+        return self._cache_shardings(cache_abstract)
+
+    def page_pool_shardings(self):
+        """Shardings of the page pool that the paged decode and verify
+        programs take (None without a mesh): pages shard over heads and
+        head_dim; the page axis stays replicated, since any sequence's
+        table may reference any page."""
+        if self.mesh is None:
+            return None
+        from repro.layers.attention import KVCache
+
+        pctx = self.decode_ctx
+        page_sh = self._sd(pctx, None, "layers", "kv_heads", None, "head_dim")
+        if self.kv_dtype != "fp":
+            from repro.quant.kv_quant import QuantKV
+
+            leaf_sh = QuantKV(page_sh, self._sd(pctx, None, "layers", "kv_heads", None))
+        else:
+            leaf_sh = page_sh
+        return KVCache(leaf_sh, leaf_sh)
 
     def _cache_shardings(self, cache_abstract):
         """Decode-layout cache shardings: KV sequence over the model axis,

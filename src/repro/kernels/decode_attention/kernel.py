@@ -31,18 +31,62 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
-from repro.quant.kv_quant import unpack_int4
-
 NEG_INF = -1e30
 
 
-def _dequant_tile(q_tile, s_tile, kv_dtype):
-    """In-VMEM dequant of one (bk, Dp) payload tile + (bk,) scale row -> f32
-    (bk, D).  This is the *fused* step: packed bytes are what the DMA moved;
-    the fp tile exists only in registers/VMEM, never in HBM."""
-    q = unpack_int4(q_tile) if kv_dtype == "int4" else q_tile
-    return q.astype(jnp.float32) * s_tile.astype(jnp.float32)[:, None]
+def _kv_planes(tile, kv_dtype):
+    """In-VMEM unpack of one (bk, Dp) payload tile -> the f32 integer planes
+    of its head_dim: ``[q]`` for int8; ``[even, odd]`` nibbles for int4,
+    each (bk, D/2), sign-extended in int32 (no lane interleave, which Mosaic
+    cannot lower).  This is the *fused* step: packed bytes are what the DMA
+    moved; fp values exist only in VMEM, never in HBM."""
+    if kv_dtype == "int4":
+        w = tile.astype(jnp.int32)
+        return [((w << 28) >> 28).astype(jnp.float32), ((w << 24) >> 28).astype(jnp.float32)]
+    return [tile.astype(jnp.float32)]
+
+
+def split_q_planes(q: jax.Array, kv_dtype: str) -> jax.Array:
+    """(B, Hkv, G, D) -> (B, Hkv, P, G, D/P): the query's head_dim split the
+    way ``_kv_planes`` splits the payload (P = 2 even/odd planes for int4)."""
+    if kv_dtype != "int4":
+        return q[:, :, None]
+    b, hkv, g, d = q.shape
+    return jnp.moveaxis(q.reshape(b, hkv, g, d // 2, 2), 4, 2)
+
+
+def merge_out_planes(out: jax.Array) -> jax.Array:
+    """Inverse of :func:`split_q_planes` on the (B, Hkv, P, G, D/P) output."""
+    b, hkv, n, g, dp = out.shape
+    return jnp.moveaxis(out, 2, 4).reshape(b, hkv, g, n * dp)
+
+
+def _quant_softmax_step(q_ref, kq_tile, ks_row, vq_tile, vs_row, pos, start, length,
+                        m_ref, l_ref, acc_ref, *, sm_scale, kv_dtype):
+    """One online-softmax block over a quantized K/V tile.
+
+    ``q . (kq * ks)^T == (q . kq^T) * ks`` and ``p . (vq * vs) == (p * vs) . vq``
+    with ``ks``/``vs`` the (1, bk) scale rows of the tile's tokens: the rows
+    broadcast along lanes of the (G, bk) scores, with no relayout.  q_ref is
+    (1, 1, P, G, D/P) and acc_ref (P, G, D/P), one plane per payload plane."""
+    ks = _kv_planes(kq_tile, kv_dtype)
+    vs = _kv_planes(vq_tile, kv_dtype)
+    s = sum(
+        jax.lax.dot_general(q_ref[0, 0, i].astype(jnp.float32), k, (((1,), (1,)), ((), ())))
+        for i, k in enumerate(ks)
+    ) * (ks_row * sm_scale)
+    s = jnp.where(jnp.logical_and(pos >= start, pos < length), s, NEG_INF)
+
+    m_prev = m_ref[...][:, :1]
+    l_prev = l_ref[...][:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_ref.shape)
+    pv = p * vs_row
+    for i, v in enumerate(vs):
+        acc_ref[i] = acc_ref[i] * alpha + jax.lax.dot_general(pv, v, (((1,), (0,)), ((), ())))
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
 
 def _decode_kernel(
@@ -162,7 +206,7 @@ def decode_attention_pallas(
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),  # l
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),  # m
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -172,12 +216,12 @@ def decode_attention_pallas(
 def _decode_quant_kernel(
     start_ref,  # scalar-prefetch: (B,) int32
     len_ref,  # scalar-prefetch: (B,) int32
-    q_ref,  # (1, 1, G, D)
+    q_ref,  # (1, 1, P, G, D/P) — split_q_planes
     kq_ref,  # (1, 1, bk, Dp) int8 / uint8 packed payload
-    ks_ref,  # (1, 1, bk) f32 scale rows
+    ks_ref,  # (1, 1, 1, bk) f32 scale row
     vq_ref,  # (1, 1, bk, Dp)
-    vs_ref,  # (1, 1, bk)
-    out_ref,  # (1, 1, G, D)
+    vs_ref,  # (1, 1, 1, bk)
+    out_ref,  # (1, 1, P, G, D/P)
     out_l_ref,
     out_m_ref,
     m_ref,
@@ -192,7 +236,7 @@ def _decode_quant_kernel(
     """Fused-dequant decode RM: identical online-softmax walk to
     ``_decode_kernel`` but the K/V streams are the *packed* cache — the DMA
     moves 1/2 (int8) or 1/4 (int4) of the fp bytes plus a 4-byte scale per
-    row, and dequant happens on the VMEM tile right before the dot."""
+    row, and dequant is fused into the block's dots."""
     b = pl.program_id(0)
     t = pl.program_id(2)
     length = len_ref[b]
@@ -206,23 +250,12 @@ def _decode_quant_kernel(
 
     @pl.when(jnp.logical_and(t * bk < length, (t + 1) * bk > start))
     def _step():
-        q = q_ref[...].astype(jnp.float32)[0, 0]  # (G, D)
-        k = _dequant_tile(kq_ref[...][0, 0], ks_ref[...][0, 0], kv_dtype)  # (bk, D)
-        v = _dequant_tile(vq_ref[...][0, 0], vs_ref[...][0, 0], kv_dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale
         pos = t * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        s = jnp.where(jnp.logical_and(pos >= start, pos < length), s, NEG_INF)
-
-        m_prev = m_ref[...][:, :1]
-        l_prev = l_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ()))
+        _quant_softmax_step(
+            q_ref, kq_ref[...][0, 0], ks_ref[...][0, 0],
+            vq_ref[...][0, 0], vs_ref[...][0, 0], pos, start, length,
+            m_ref, l_ref, acc_ref, sm_scale=sm_scale, kv_dtype=kv_dtype,
         )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(t == n_steps - 1)
     def _finalize():
@@ -251,7 +284,9 @@ def decode_attention_quant_pallas(
     contiguous cache.  Same outputs (normalized out + l/m stats)."""
     b, hkv, g, d = q.shape
     s = k_q.shape[2]
-    bk = min(bk, s)
+    # The (1, bk) scale-row block must be lane-aligned unless it spans the
+    # whole cache (TPU block tiling), so bk rounds up to a multiple of 128.
+    bk = min(-(-bk // 128) * 128, s)
     pad = (-s) % bk
     if pad:
         pad4 = ((0, 0), (0, 0), (0, pad), (0, 0))
@@ -264,6 +299,13 @@ def decode_attention_quant_pallas(
         sm_scale = 1.0 / math.sqrt(d)
     n_steps = (s + pad) // bk
     dp = k_q.shape[3]
+    # Scale rows go in as (B, Hkv, 1, S): a (1, bk) block of one head obeys
+    # the TPU block-tiling rule, a (1, 1, bk) block of (B, Hkv, S) does not.
+    k_scale = k_scale[:, :, None, :]
+    v_scale = v_scale[:, :, None, :]
+
+    qp = split_q_planes(q, kv_dtype)
+    n_planes, dq = qp.shape[2], qp.shape[4]
 
     if starts is None:
         starts = jnp.zeros_like(lengths)
@@ -274,33 +316,34 @@ def decode_attention_quant_pallas(
         num_scalar_prefetch=2,
         grid=(b, hkv, n_steps),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, n_planes, g, dq), lambda bi, hi, ti, *_: (bi, hi, 0, 0, 0)),
             pl.BlockSpec((1, 1, bk, dp), lambda bi, hi, ti, *_: (bi, hi, ti, 0)),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, ti, *_: (bi, hi, ti)),
+            pl.BlockSpec((1, 1, 1, bk), lambda bi, hi, ti, *_: (bi, hi, 0, ti)),
             pl.BlockSpec((1, 1, bk, dp), lambda bi, hi, ti, *_: (bi, hi, ti, 0)),
-            pl.BlockSpec((1, 1, bk), lambda bi, hi, ti, *_: (bi, hi, ti)),
+            pl.BlockSpec((1, 1, 1, bk), lambda bi, hi, ti, *_: (bi, hi, 0, ti)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, n_planes, g, dq), lambda bi, hi, ti, *_: (bi, hi, 0, 0, 0)),
             pl.BlockSpec((1, 1, g, 128), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, g, 128), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((n_planes, g, dq), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out, out_l, out_m = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_planes, g, dq), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(starts.astype(jnp.int32), lengths.astype(jnp.int32), q, k_q, k_scale, v_q, v_scale)
+    )(starts.astype(jnp.int32), lengths.astype(jnp.int32), qp, k_q, k_scale, v_q, v_scale)
+    return merge_out_planes(out), out_l, out_m

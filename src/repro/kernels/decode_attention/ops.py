@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.kernel import (
     decode_attention_pallas,
     decode_attention_quant_pallas,
@@ -79,7 +80,6 @@ def decode_attention(
     *,
     bk: int = 512,
     use_kernel: bool = False,
-    interpret: bool = True,
     sm_scale: Optional[float] = None,
     return_stats: bool = False,
     k_scales: Optional[jax.Array] = None,  # (B, Hkv, S) f32 — quantized cache
@@ -106,7 +106,7 @@ def decode_attention(
                 q.reshape(b, hkv, g, d), k, k_scales, v, v_scales,
                 lengths.astype(jnp.int32),
                 None if starts is None else starts.astype(jnp.int32),
-                kv_dtype=kv_dtype, bk=bk, interpret=interpret, sm_scale=sm_scale,
+                kv_dtype=kv_dtype, bk=bk, interpret=interpret_mode(), sm_scale=sm_scale,
             )
             if return_stats:
                 return (out.reshape(b, h, d),
@@ -127,7 +127,7 @@ def decode_attention(
     # the kernel clamps bk to the cache and pads any partial final block
     out, l, m = decode_attention_pallas(
         qg, k, v, lengths.astype(jnp.int32), None if starts is None else starts.astype(jnp.int32),
-        bk=bk, interpret=interpret, sm_scale=sm_scale
+        bk=bk, interpret=interpret_mode(), sm_scale=sm_scale
     )
     if return_stats:
         return (out.reshape(b, h, d),

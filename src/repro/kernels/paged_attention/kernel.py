@@ -25,8 +25,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
-from repro.kernels.decode_attention.kernel import _dequant_tile
+from repro.kernels.decode_attention.kernel import (
+    _quant_softmax_step,
+    merge_out_planes,
+    split_q_planes,
+)
 
 NEG_INF = -1e30
 
@@ -142,7 +145,7 @@ def paged_decode_attention_pallas(
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),  # l
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),  # m
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -161,12 +164,12 @@ def _paged_decode_quant_kernel(
     tables_ref,  # scalar-prefetch: (B, P) int32
     start_ref,  # scalar-prefetch: (B,) int32
     len_ref,  # scalar-prefetch: (B,) int32
-    q_ref,  # (1, 1, G, D)
+    q_ref,  # (1, 1, P, G, D/P) — split_q_planes
     kq_ref,  # (1, 1, bs, Dp) packed payload of page tables_ref[b, t]
-    ks_ref,  # (1, 1, bs) f32 scale rows of the same page
+    ks_ref,  # (1, 1, 1, bs) f32 scale row of the same page
     vq_ref,  # (1, 1, bs, Dp)
-    vs_ref,  # (1, 1, bs)
-    out_ref,  # (1, 1, G, D)
+    vs_ref,  # (1, 1, 1, bs)
+    out_ref,  # (1, 1, P, G, D/P)
     out_l_ref,
     out_m_ref,
     m_ref,
@@ -196,23 +199,12 @@ def _paged_decode_quant_kernel(
 
     @pl.when(jnp.logical_and(t * bs < length, (t + 1) * bs > start))
     def _step():
-        q = q_ref[...].astype(jnp.float32)[0, 0]  # (G, D)
-        k = _dequant_tile(kq_ref[...][0, 0], ks_ref[...][0, 0], kv_dtype)  # (bs, D)
-        v = _dequant_tile(vq_ref[...][0, 0], vs_ref[...][0, 0], kv_dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * sm_scale  # (G, bs)
         pos = t * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(jnp.logical_and(pos >= start, pos < length), s, NEG_INF)
-
-        m_prev = m_ref[...][:, :1]
-        l_prev = l_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ()))
+        _quant_softmax_step(
+            q_ref, kq_ref[...][0, 0], ks_ref[...][0, 0],
+            vq_ref[...][0, 0], vs_ref[...][0, 0], pos, start, length,
+            m_ref, l_ref, acc_ref, sm_scale=sm_scale, kv_dtype=kv_dtype,
         )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(t == n_pages - 1)
     def _finalize():
@@ -245,6 +237,12 @@ def paged_decode_attention_quant_pallas(
     n_pages = block_tables.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    # (N, Hkv, 1, bs) scale planes: see decode_attention_quant_pallas.
+    k_scales = k_scales[:, :, None, :]
+    v_scales = v_scales[:, :, None, :]
+
+    qp = split_q_planes(q, kv_dtype)
+    n_planes, dq = qp.shape[2], qp.shape[4]
 
     if starts is None:
         starts = jnp.zeros_like(lengths)
@@ -255,32 +253,32 @@ def paged_decode_attention_quant_pallas(
         num_scalar_prefetch=3,
         grid=(b, hkv, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ti, tbl, *_: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, n_planes, g, dq), lambda bi, hi, ti, *_: (bi, hi, 0, 0, 0)),
             pl.BlockSpec((1, 1, bs, dp), lambda bi, hi, ti, tbl, *_: (tbl[bi, ti], hi, 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda bi, hi, ti, tbl, *_: (tbl[bi, ti], hi, 0)),
+            pl.BlockSpec((1, 1, 1, bs), lambda bi, hi, ti, tbl, *_: (tbl[bi, ti], hi, 0, 0)),
             pl.BlockSpec((1, 1, bs, dp), lambda bi, hi, ti, tbl, *_: (tbl[bi, ti], hi, 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda bi, hi, ti, tbl, *_: (tbl[bi, ti], hi, 0)),
+            pl.BlockSpec((1, 1, 1, bs), lambda bi, hi, ti, tbl, *_: (tbl[bi, ti], hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, n_planes, g, dq), lambda bi, hi, ti, *_: (bi, hi, 0, 0, 0)),
             pl.BlockSpec((1, 1, g, 128), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, g, 128), lambda bi, hi, ti, *_: (bi, hi, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((n_planes, g, dq), jnp.float32),
         ],
     )
     out, out_l, out_m = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_planes, g, dq), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -288,10 +286,10 @@ def paged_decode_attention_quant_pallas(
         jnp.clip(block_tables, 0, n - 1).astype(jnp.int32),
         starts.astype(jnp.int32),
         lengths.astype(jnp.int32),
-        q,
+        qp,
         k_pages_q,
         k_scales,
         v_pages_q,
         v_scales,
     )
-    return out, out_l, out_m
+    return merge_out_planes(out), out_l, out_m

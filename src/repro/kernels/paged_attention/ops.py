@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.ops import _decode_attention_streaming
 from repro.kernels.paged_attention.kernel import (
     paged_decode_attention_pallas,
@@ -77,7 +78,6 @@ def paged_decode_attention(
     starts: Optional[jax.Array] = None,  # (B,) int32 — sliding-window start
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
     sm_scale: Optional[float] = None,
     return_stats: bool = False,
     k_scales: Optional[jax.Array] = None,  # (N, Hkv, bs) f32 — quantized pool
@@ -106,7 +106,7 @@ def paged_decode_attention(
                 qg, k_pages, k_scales, v_pages, v_scales,
                 block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
                 None if starts is None else starts.astype(jnp.int32),
-                kv_dtype=kv_dtype, interpret=interpret, sm_scale=sm_scale,
+                kv_dtype=kv_dtype, interpret=interpret_mode(), sm_scale=sm_scale,
             )
             if return_stats:
                 return (out.reshape(b, h, d),
@@ -138,7 +138,7 @@ def paged_decode_attention(
         qg, k_pages, v_pages, block_tables.astype(jnp.int32),
         lengths.astype(jnp.int32),
         None if starts is None else starts.astype(jnp.int32),
-        interpret=interpret, sm_scale=sm_scale,
+        interpret=interpret_mode(), sm_scale=sm_scale,
     )
     if return_stats:
         return (out.reshape(b, h, d),

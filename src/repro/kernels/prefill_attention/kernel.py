@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -124,7 +122,7 @@ def prefill_attention_pallas(
             pltpu.VMEM((blk, 128), jnp.float32),
             pltpu.VMEM((blk, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

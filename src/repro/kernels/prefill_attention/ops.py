@@ -6,6 +6,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.prefill_attention.kernel import prefill_attention_pallas
 from repro.kernels.prefill_attention.ref import prefill_attention_reference
 
@@ -26,15 +27,14 @@ def prefill_attention(
     blk: int = 256,
     schedule: str = "reverse",
     use_kernel: bool = False,
-    interpret: bool = True,
     window: Optional[int] = None,
     sm_scale: Optional[float] = None,
 ) -> jax.Array:
     """Causal self-attention over a full prompt, (B,H,S,D) layout.
 
     use_kernel=False runs the jnp oracle (CPU-fast path used inside jitted
-    model code); use_kernel=True runs the Pallas prefill RM (TPU target,
-    interpret=True on CPU).  Sliding windows fall back to the oracle — the
+    model code); use_kernel=True runs the Pallas prefill RM (compiled on a
+    TPU, interpreted on the CPU).  Sliding windows fall back to the oracle — the
     hymba SWA layers are never the prefill bottleneck.
     """
     if not use_kernel or window is not None:
@@ -47,6 +47,6 @@ def prefill_attention(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     out = prefill_attention_pallas(
-        q, k, v, blk=blk, schedule=schedule, interpret=interpret, sm_scale=sm_scale
+        q, k, v, blk=blk, schedule=schedule, interpret=interpret_mode(), sm_scale=sm_scale
     )
     return out[:, :, :s] if pad else out

@@ -29,25 +29,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
 
-
-def _decode_ternary_tile(wp: jax.Array) -> jax.Array:
-    """uint8 (bk/4, bn) -> int8 (bk, bn) inside the kernel.
+def _decode_ternary_part(wp: jax.Array, i: int) -> jax.Array:
+    """uint8 (bk/4, bn) -> int8 (bk/4, bn): the weights of code slot ``i``.
 
     Value k = 4j + i sits in bits [2i, 2i+2) of byte j (codes 0/+1/-1 =
-    0b00/0b01/0b10).  The stack+reshape is a sublane interleave; an
-    alternative that avoids it is four strided dots
-    acc += sum_i dot(x[:, i::4], part_i) — measured equivalent in interpret
-    mode, kept simple here.
+    0b00/0b01/0b10).  The decode widens to int32 and maps codes by
+    arithmetic, ``(c & 1) - (c >> 1)``: v5e has no 8-bit vector compares.
     """
-    parts = []
-    for i in range(4):
-        bits = (wp >> (2 * i)) & 0x3
-        val = jnp.where(bits == 1, jnp.int8(1), jnp.where(bits == 2, jnp.int8(-1), jnp.int8(0)))
-        parts.append(val)
-    kq, bn = wp.shape
-    return jnp.stack(parts, axis=1).reshape(kq * 4, bn)
+    c = (wp.astype(jnp.int32) >> (2 * i)) & 0x3
+    return ((c & 1) - (c >> 1)).astype(jnp.int8)
 
 
 def _tlmm_kernel(x_ref, wp_ref, scale_ref, out_ref, acc_ref, *, n_k_steps: int, out_dtype):
@@ -57,14 +48,18 @@ def _tlmm_kernel(x_ref, wp_ref, scale_ref, out_ref, acc_ref, *, n_k_steps: int, 
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]  # (bm, bk) int8
-    w = _decode_ternary_tile(wp_ref[...])  # (bk, bn) int8
-    acc_ref[...] += jax.lax.dot_general(
-        x,
-        w,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    # x arrives slot-major within each K block (see tlmm_pallas), so code
+    # slot i multiplies the contiguous lane slice [i*q, (i+1)*q) of x: four
+    # int8 MXU dots, and no sublane interleave of the decoded weights.
+    wp = wp_ref[...]  # (bk/4, bn) uint8
+    q = wp.shape[0]
+    for i in range(4):
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[:, i * q:(i + 1) * q],
+            _decode_ternary_part(wp, i),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
 
     @pl.when(k_step == n_k_steps - 1)
     def _finalize():
@@ -94,6 +89,9 @@ def tlmm_pallas(
     assert bk % 4 == 0
     n_k_steps = k // bk
 
+    # Slot-major order within each K block: column 4j + i of a block moves
+    # to i * bk/4 + j, next to the other weights packed in bit slot i.
+    x_q = x_q.reshape(m, n_k_steps, bk // 4, 4).swapaxes(2, 3).reshape(m, k)
     grid = (m // bm, n // bn, n_k_steps)
     kernel = functools.partial(_tlmm_kernel, n_k_steps=n_k_steps, out_dtype=out_dtype)
     return pl.pallas_call(
@@ -107,7 +105,7 @@ def tlmm_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

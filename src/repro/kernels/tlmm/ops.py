@@ -3,9 +3,9 @@
 ``tlmm_matmul`` is what :class:`repro.layers.linear.TernaryLinear` calls: it
 quantizes activations per-token to int8 (A8), folds the BitNet weight scale
 into the per-row activation scale, pads M to the sublane tile, and dispatches
-to the Pallas kernel (interpret=True on CPU) or the jnp reference (the
-default under jit on CPU — identical numerics, faster to compile; the Pallas
-path is exercised by the kernel tests and is the TPU target).
+to the Pallas kernel (interpreted on the CPU, see
+:func:`repro.kernels.interpret_mode`) or the jnp reference (identical
+numerics, faster to compile on the CPU).
 """
 from __future__ import annotations
 
@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.quant.act_quant import quantize_activations_int8
 from repro.quant.ternary import TernaryWeight
 from repro.kernels.tlmm.kernel import tlmm_pallas
@@ -37,7 +38,6 @@ def tlmm_matmul(
     *,
     out_dtype=jnp.bfloat16,
     use_kernel: bool = False,
-    interpret: bool = True,
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
@@ -67,6 +67,7 @@ def tlmm_matmul(
         x_q = jnp.pad(x_q, ((0, mp - m), (0, 0)))
         scale = jnp.pad(scale, ((0, mp - m), (0, 0)))
     y = tlmm_pallas(
-        x_q, w.packed, scale, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype, interpret=interpret
+        x_q, w.packed, scale, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
+        interpret=interpret_mode(),
     )[:m]
     return y.reshape(*lead, n)

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import ALL_ARCHS, get_config, reduced_config
 from repro.models import get_model
 from repro.serving import (
@@ -212,7 +213,7 @@ async def serve_http(core: EngineCore, default_params: SamplingParams,
                   f"(POST /generate streams SSE, GET /stats, GET /metrics)")
             if ready is not None:
                 ready.set()
-            async with server:
+            try:
                 try:
                     await stop.wait()
                 except asyncio.CancelledError:
@@ -225,8 +226,14 @@ async def serve_http(core: EngineCore, default_params: SamplingParams,
                         core.has_unfinished()
                         or eng.snapshot()["frontend"]["open_streams"]):
                     await asyncio.sleep(0.02)
-            # AsyncEngine.__aexit__ now aborts anything still unfinished and
-            # routes each stream its terminal delta before the loop exits
+                # abort whatever is still running and route each stream its
+                # terminal delta BEFORE closing the server: since Python
+                # 3.12 Server.wait_closed() also waits for open connections,
+                # and a stream's handler returns only after that delta
+                await eng.shutdown()
+            finally:
+                server.close()
+                await server.wait_closed()
     finally:
         for sig in hooked:
             loop.remove_signal_handler(sig)
@@ -310,6 +317,7 @@ def main(argv=None) -> int:
     p.add_argument("--stop-token", type=int, action="append", default=None,
                    help="token id that ends generation (repeatable)")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     assert cfg.family == "transformer", "serving engine drives the transformer family"
@@ -461,7 +469,7 @@ def main(argv=None) -> int:
         trace = TRACER.export_chrome_trace(args.trace_out)
         print(f"  trace             : {len(trace['traceEvents'])} events -> "
               f"{args.trace_out} ({TRACER.dropped} dropped)")
-    return 0
+    return 0 if len(eng.finished) == args.requests else 1
 
 
 if __name__ == "__main__":

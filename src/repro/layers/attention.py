@@ -160,7 +160,7 @@ def attention_prefill(
         # kernel's exact analytic cost.  Projections/KV collection stay real.
         out = qt
     elif cfg.use_pallas and window is None and causal and qt.shape[2] == kt.shape[2]:
-        out = prefill_attention(qt, kt, vt, use_kernel=True, interpret=True)
+        out = prefill_attention(qt, kt, vt, use_kernel=True)
     elif s <= 1024 and kt.shape[2] <= 1024:
         from repro.kernels.prefill_attention.ref import prefill_attention_reference
 
@@ -765,7 +765,7 @@ def attention_decode(
             out = qd
         else:
             eff_len = jnp.full((b,), cross_len if cross_len is not None else kt.shape[2], jnp.int32)
-            out = decode_attention(qd, kt, vt, eff_len, use_kernel=cfg.use_pallas, interpret=True)
+            out = decode_attention(qd, kt, vt, eff_len, use_kernel=cfg.use_pallas)
         y = out.reshape(b, 1, h * hd)
         y = linear_apply(params["wo"], y, quant=cfg.quant, training=False, use_pallas=cfg.use_pallas)
         return y, cache
@@ -774,7 +774,7 @@ def attention_decode(
         k_arr, v_arr, qkw = _kv_leaf_args(cache.k, cache.v)
         return decode_attention(
             qd, k_arr, v_arr, lengths.astype(jnp.int32), starts,
-            use_kernel=cfg.use_pallas, interpret=True, return_stats=True, **qkw,
+            use_kernel=cfg.use_pallas, return_stats=True, **qkw,
         )
 
     return _decode_new_token(params, x, lengths, cfg, window, attend)
@@ -831,7 +831,7 @@ def attention_decode_paged(
         k_arr, v_arr, qkw = _kv_leaf_args(k_pages, v_pages)
         return paged_decode_attention(
             qd, k_arr, v_arr, block_tables, lengths.astype(jnp.int32), starts,
-            use_kernel=cfg.use_pallas, interpret=True, return_stats=True, **qkw,
+            use_kernel=cfg.use_pallas, return_stats=True, **qkw,
         )
 
     return _decode_new_token(params, x, lengths, cfg, window, attend)

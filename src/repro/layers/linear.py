@@ -46,12 +46,11 @@ def linear_apply(
     *,
     training: bool = False,
     use_pallas: bool = False,
-    interpret: bool = True,
 ) -> jax.Array:
     w = params["w"]
     if isinstance(w, TernaryWeight):
         # inference TLMM path (packed 2-bit weights)
-        y = tlmm_matmul(x, w, out_dtype=x.dtype, use_kernel=use_pallas, interpret=interpret)
+        y = tlmm_matmul(x, w, out_dtype=x.dtype, use_kernel=use_pallas)
     elif quant.ternary:
         if training:
             # BitNet QAT: STE through both weight and activation quantizers

@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -225,7 +224,7 @@ def moe_apply(
         )
         return y.reshape(bl, sl, d)
 
-    y = shard_map(
+    y = jax.shard_map(
         shard_fn,
         mesh=pctx.mesh,
         in_specs=(w_specs, x_spec, x_spec),

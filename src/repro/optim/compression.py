@@ -19,7 +19,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common.compat import shard_map
 
 
 def _quant_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -35,8 +34,7 @@ def compressed_mean_over_axis(grads: Any, err: Any, axis: str) -> Tuple[Any, Any
     Returns (mean_grads f32, new_error_feedback).  Must run inside shard_map
     with ``axis`` manual.
     """
-    # jax.lax.axis_size is newer-jax; psum of 1 is the portable axis size
-    n = jax.lax.psum(1, axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         g32 = g.astype(jnp.float32) + e
@@ -83,7 +81,7 @@ def compressed_dp_grads(loss_fn, mesh, *, pod_axis: str = "pod", batch_spec=None
         return loss, mean, err
 
     rep = None  # replicated pytrees: spec inferred as fully-replicated
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), batch_spec),

@@ -331,6 +331,8 @@ class ModelRunner:
                 # full provisioning: every slot can grow to max_len
                 num_blocks = n_slots * cdiv(max_len, block_size)
             pool_kv = T.init_paged_pool(cfg, num_blocks, block_size, kv_dtype=kv_dtype)
+            if mesh is not None:  # the layout the decode program takes
+                pool_kv = jax.device_put(pool_kv, self.engine.page_pool_shardings())
             self.paged = PagedKVCache(
                 pool_kv, n_slots=n_slots, max_len=max_len, block_size=block_size
             )
@@ -355,6 +357,9 @@ class ModelRunner:
             self.relay_static = jax.jit(relay_static)
             self.decode_prog = self.engine.decode_program(self._pa, n_slots, max_len)
             self.cache = T.init_cache(cfg, n_slots, max_len, kv_dtype=kv_dtype)
+            if mesh is not None:  # slot installs keep this layout
+                self.cache = jax.device_put(
+                    self.cache, self.engine.cache_shardings(n_slots, max_len))
         self.last_tokens = jnp.zeros((n_slots,), jnp.int32)
 
         # Speculative decoding: ONE verify program shape (n_slots, k+1)

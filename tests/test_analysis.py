@@ -292,19 +292,30 @@ def _bad_kernel_ops():
         return pl.pallas_call(
             lambda x_ref, o_ref: None,
             grid=(n // 8,),
-            in_specs=[pl.BlockSpec((8,), lambda i: (i,))],
-            out_specs=pl.BlockSpec((8,), lambda i: (i,)),
-            out_shape=jnp.zeros((n,), jnp.float32),
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
+            out_shape=jnp.zeros((n, 128), jnp.float32),
         )(x)
 
-    return oob_index_map, bad_divisibility, fp_materializing_quant, clean
+    def bad_tiling(scale):  # (1, 1, bk) scale rows of a (B, Hkv, S) plane
+        b, hkv, s = scale.shape
+        bk = 128
+        return pl.pallas_call(
+            lambda s_ref, o_ref: None,
+            grid=(b, hkv, s // bk),
+            in_specs=[pl.BlockSpec((1, 1, bk), lambda bi, hi, ti: (bi, hi, ti))],
+            out_specs=pl.BlockSpec((1, 1, bk), lambda bi, hi, ti: (bi, hi, ti)),
+            out_shape=jnp.zeros(scale.shape, jnp.float32),
+        )(scale)
+
+    return oob_index_map, bad_divisibility, fp_materializing_quant, clean, bad_tiling
 
 
 def test_kernel_checker_catches_oob_index_map():
     import jax.numpy as jnp
     from repro.analysis.kernel_check import KernelCase, check_op
 
-    oob, _, _, _ = _bad_kernel_ops()
+    oob, _, _, _, _ = _bad_kernel_ops()
     found = check_op(oob, [KernelCase("oob", (jnp.zeros(64, jnp.float32),), {})])
     assert "kernel:index-oob" in _rules(found)
 
@@ -313,7 +324,7 @@ def test_kernel_checker_catches_block_divisibility():
     import jax.numpy as jnp
     from repro.analysis.kernel_check import KernelCase, check_op
 
-    _, baddiv, _, _ = _bad_kernel_ops()
+    _, baddiv, _, _, _ = _bad_kernel_ops()
     found = check_op(
         baddiv, [KernelCase("div", (jnp.zeros(64, jnp.float32),), {})])
     assert "kernel:block-divisibility" in _rules(found)
@@ -323,7 +334,7 @@ def test_kernel_checker_catches_fp_cache_materialization():
     import jax.numpy as jnp
     from repro.analysis.kernel_check import KernelCase, check_op
 
-    _, _, fpmat, _ = _bad_kernel_ops()
+    _, _, fpmat, _, _ = _bad_kernel_ops()
     k_q = jnp.zeros((4, 2, 128, 16), jnp.int8)
     k_scale = jnp.ones((4, 2, 128), jnp.float32)
     found = check_op(fpmat, [KernelCase(
@@ -335,11 +346,26 @@ def test_kernel_checker_clean_op_passes():
     import jax.numpy as jnp
     from repro.analysis.kernel_check import KernelCase, check_op
 
-    _, _, _, clean = _bad_kernel_ops()
+    _, _, _, clean, _ = _bad_kernel_ops()
     found = check_op(
         clean,
-        [KernelCase("ok", (jnp.zeros(64, jnp.float32),), {}, fp_elems=10**9)])
+        [KernelCase("ok", (jnp.zeros((64, 128), jnp.float32),), {}, fp_elems=10**9)])
     assert found == []
+
+
+def test_kernel_checker_catches_tpu_block_tiling():
+    """The scale-plane block (1, 1, bk) over (B, Hkv=24, S) passes interpret
+    mode but Mosaic refuses it: second-minor 1 is neither a multiple of 8
+    nor Hkv.  The same rows as a (1, 1, 1, bk) block of (B, Hkv, 1, S) pass."""
+    import jax.numpy as jnp
+    from repro.analysis.kernel_check import KernelCase, check_op, tpu_tiling_ok
+
+    _, _, _, _, bad = _bad_kernel_ops()
+    found = check_op(bad, [KernelCase("scale", (jnp.ones((2, 24, 512), jnp.float32),), {})])
+    assert "kernel:block-tiling" in _rules(found)
+    assert tpu_tiling_ok((1, 1, 1, 128), (2, 24, 1, 512), jnp.float32)
+    assert tpu_tiling_ok((128,), (1024,), jnp.float32)
+    assert not tpu_tiling_ok((128,), (1024,), jnp.int8)
 
 
 # ---------------------------------------------------------------- baseline --
@@ -415,7 +441,7 @@ def test_progcheck_dtype_flow_catches_f64():
     import jax.numpy as jnp
     from repro.analysis import progcheck
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jax.ShapeDtypeStruct((8,), jnp.float32))
     got, emit = _collect()

@@ -48,14 +48,14 @@ def _qkv(b, h, hkv, s, d, seed=0, dtype=jnp.float32):
 def test_prefill_kernel_sweep(schedule, b, h, hkv, s, d, blk):
     q, k, v = _qkv(b, h, hkv, s, d, seed=s + h)
     ref = prefill_attention_reference(q, k, v)
-    out = prefill_attention(q, k, v, blk=blk, schedule=schedule, use_kernel=True, interpret=True)
+    out = prefill_attention(q, k, v, blk=blk, schedule=schedule, use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
 def test_prefill_bf16():
     q, k, v = _qkv(1, 2, 2, 128, 64, dtype=jnp.bfloat16)
     ref = prefill_attention_reference(q, k, v)
-    out = prefill_attention(q, k, v, blk=64, use_kernel=True, interpret=True)
+    out = prefill_attention(q, k, v, blk=64, use_kernel=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=3e-2, atol=3e-2
     )
@@ -64,8 +64,8 @@ def test_prefill_bf16():
 def test_prefill_reverse_equals_forward():
     """The paper's reverse schedule is a pure reordering — identical output."""
     q, k, v = _qkv(2, 4, 4, 256, 64, seed=3)
-    a = prefill_attention(q, k, v, blk=64, schedule="reverse", use_kernel=True, interpret=True)
-    b = prefill_attention(q, k, v, blk=64, schedule="forward", use_kernel=True, interpret=True)
+    a = prefill_attention(q, k, v, blk=64, schedule="reverse", use_kernel=True)
+    b = prefill_attention(q, k, v, blk=64, schedule="forward", use_kernel=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
 
 
@@ -85,7 +85,7 @@ def test_decode_kernel_sweep(b, h, hkv, s, d, bk):
     v = jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.float32)
     lengths = jnp.asarray(rng.integers(1, s + 1, size=(b,)), jnp.int32)
     ref = decode_attention(q, k, v, lengths, use_kernel=False)
-    out = decode_attention(q, k, v, lengths, bk=bk, use_kernel=True, interpret=True)
+    out = decode_attention(q, k, v, lengths, bk=bk, use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
@@ -109,7 +109,7 @@ def test_decode_window_property(b, hkv, g, s, seed):
     window = int(rng.integers(1, length + 1))
     lengths = jnp.full((b,), length, jnp.int32)
     starts = jnp.full((b,), length - window, jnp.int32)
-    out = decode_attention(q, k, v, lengths, starts, bk=32, use_kernel=True, interpret=True)
+    out = decode_attention(q, k, v, lengths, starts, bk=32, use_kernel=True)
     # oracle: zero-out everything outside the window by slicing
     ref = decode_attention(
         q, k[:, :, length - window : length], v[:, :, length - window : length],
@@ -139,7 +139,7 @@ def test_decode_stats_merge_matches_appended_cache(use_kernel):
     sm = 1.0 / math.sqrt(d)
 
     out_c, l_c, m_c = decode_attention(
-        q, k, v, lengths, use_kernel=use_kernel, interpret=True, bk=32, return_stats=True
+        q, k, v, lengths, use_kernel=use_kernel, bk=32, return_stats=True
     )
     merged = _merge_new_token(out_c, l_c, m_c, q, k_new, v_new, sm)
 
@@ -230,7 +230,7 @@ def test_decode_quant_kernel_matches_dequant_reference(kv_dtype, b, h, hkv, s, d
     ref = decode_attention(q, kq, vq, lengths, k_scales=ks, v_scales=vs,
                            kv_dtype=kv_dtype, use_kernel=False)
     out = decode_attention(q, kq, vq, lengths, k_scales=ks, v_scales=vs,
-                           kv_dtype=kv_dtype, bk=bk, use_kernel=True, interpret=True)
+                           kv_dtype=kv_dtype, bk=bk, use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 

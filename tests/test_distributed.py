@@ -100,9 +100,10 @@ def test_train_step_runs_on_small_mesh():
     _run("""
     import jax, jax.numpy as jnp
     from repro.configs import reduced_config
+    from repro.launch.mesh import make_host_mesh
     from repro.train.trainer import TrainConfig, init_train_state, jit_train_step
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_host_mesh(data=2, model=2)
     cfg = reduced_config("qwen2.5-14b")
     params, opt = init_train_state(cfg, jax.random.PRNGKey(0), mesh, dtype=jnp.float32)
     step = jit_train_step(cfg, TrainConfig(), mesh, jax.eval_shape(lambda: params))
@@ -170,7 +171,8 @@ def test_sharded_decode_matches_unsharded():
         return np.stack([np.asarray(t) for t in outs])
 
     ref = roll(None)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(data=2, model=2)
     out = roll(mesh)
     np.testing.assert_array_equal(ref, out)
     print("sharded decode == unsharded decode")

@@ -45,7 +45,7 @@ def test_kernel_matches_reference_shapes(m, k, n, bm, bn, bk):
     if n % bn == 0 and k % bk == 0 and m % bm == 0:
         out = tlmm_pallas(x_q, tw.packed, scale, bm=bm, bn=bn, bk=bk, out_dtype=jnp.float32, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
-    out2 = tlmm_matmul(x, tw, use_kernel=True, interpret=True, out_dtype=jnp.float32,
+    out2 = tlmm_matmul(x, tw, use_kernel=True, out_dtype=jnp.float32,
                        block_m=bm, block_n=bn, block_k=bk)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), rtol=1e-6, atol=1e-6)
 
@@ -54,7 +54,7 @@ def test_kernel_matches_reference_shapes(m, k, n, bm, bn, bk):
 def test_dtype_sweep(out_dtype):
     x, tw = _mk(16, 256, 128)
     ref = tlmm_matmul(x, tw, use_kernel=False, out_dtype=out_dtype)
-    out = tlmm_matmul(x, tw, use_kernel=True, interpret=True, out_dtype=out_dtype)
+    out = tlmm_matmul(x, tw, use_kernel=True, out_dtype=out_dtype)
     np.testing.assert_allclose(
         np.asarray(ref, np.float32), np.asarray(out, np.float32), rtol=2e-2, atol=2e-2
     )
