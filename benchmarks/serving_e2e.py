@@ -47,7 +47,6 @@ def run() -> dict:
     stats_pd, stats_st = eng_pd.stats, eng_st.stats
 
     same = all(outs_pd[k] == outs_st[k] for k in outs_pd)
-    hidden = [t.hidden_fraction for t in stats_pd.swap_timings if t.t_relayout or t.t_total_overlapped]
 
     def _row(engine, stats):
         return {"engine": engine, "decode_tokens": stats.decode_tokens,

@@ -1,17 +1,21 @@
-"""Tracing overhead: the disabled tracer must be free on the decode loop.
+"""Tracing overhead: the tracer must cost little on the decode loop.
 
-The instrumentation contract (``repro.obs.trace``): every hot-path site
-guards with ``if TRACER.enabled`` and reuses ``perf_counter`` stamps the
-stats accounting already takes, so the DISABLED cost per decode round is a
-handful of predicted-not-taken branches.  This benchmark measures that
-claim and gates it — the observability PR must not tax serving when nobody
-is watching.
+The instrumentation contract (``repro.obs.trace``): a disabled span site is
+one attribute check and a shared no-op context, so the DISABLED cost per
+decode round is a handful of branches; an enabled span also enters a
+``jax.profiler.TraceAnnotation``.  Every span of a step counts here:
+``engine.step``, ``engine.schedule``, the chunk's and the decode round's
+phases (``decode.prepare`` / ``dispatch`` / ``wait`` / ``outputs``).  This
+benchmark measures the enabled tracer against the disabled one and gates
+it — the observability PR must not tax serving when nobody is watching.
 
 Protocol: one warm engine, one seeded workload replayed as K segments per
 mode, modes INTERLEAVED (disabled, enabled, disabled, enabled, ...) so slow
 ambient drift (noisy neighbors, thermal) hits both alike instead of landing
-on whichever ran last.  Per segment the decode-round cost comes from the
-engine's own stats delta; per mode the MEDIAN segment cost is compared.
+on whichever ran last.  Per segment the cost per decode round is the
+engine's whole-step wall time (``EngineStats.t_step``, which holds every
+span site) over its decode rounds, from the stats delta; per mode the
+MEDIAN segment cost is compared.
 
 Gate: enabled-median overhead < 3 % of the disabled median, OR the absolute
 delta is under 150 us/round — on a tiny CI model a decode round is sub-ms,
@@ -36,16 +40,16 @@ REL_GATE = 0.03
 
 
 def _decode_cost_segment(eng, prompts, *, max_new, tag):
-    """Replay one workload segment; return (decode seconds, decode rounds)
+    """Replay one workload segment; return (step seconds, decode rounds)
     from the engine's own stats delta."""
     from repro.serving import Request
 
-    t0, r0 = eng.stats.t_decode, eng.stats.decode_rounds
+    t0, r0 = eng.stats.t_step, eng.stats.decode_rounds
     for i, p in enumerate(prompts):
         eng.submit(Request(f"{tag}-{i}", p.copy(), max_new=max_new))
     eng.run()
     rounds = eng.stats.decode_rounds - r0
-    return eng.stats.t_decode - t0, max(rounds, 1)
+    return eng.stats.t_step - t0, max(rounds, 1)
 
 
 def run(tiny: bool = False) -> dict:
@@ -66,7 +70,9 @@ def run(tiny: bool = False) -> dict:
                              vocab_size=512, num_heads=4, num_kv_heads=2)
         n_req, max_new, segments = 3, 64, 9
     params = get_model(cfg).init(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    eng = EngineCore(cfg, params, n_slots=n_req, max_len=16 + max_new + 8)
+    # chunked prefill, so the chunk's spans are among the sites measured
+    eng = EngineCore(cfg, params, n_slots=n_req, max_len=16 + max_new + 8,
+                     prefill_chunk=8)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
                for _ in range(n_req)]
@@ -123,9 +129,10 @@ def run(tiny: bool = False) -> dict:
             "enabled segments recorded events": events_recorded > 0,
         },
         "notes": (
-            f"Median decode-round cost over {segments} interleaved segments "
-            f"per mode ({n_req} streams x {max_new} tokens each, warm "
-            f"engine, stats-delta timing).  enabled runs with a 4096-event "
+            f"Median step time per decode round over {segments} interleaved "
+            f"segments per mode ({n_req} streams x {max_new} tokens each, "
+            f"chunked prefill, warm engine, stats-delta timing, every span "
+            f"site of the step included).  enabled runs with a 4096-event "
             f"ring so eviction cost is included.  Overhead "
             f"{100 * rel:+.2f}% ({1e6 * delta:+.1f} us/round) — gate: "
             f"< {100 * REL_GATE:.0f}% relative or "

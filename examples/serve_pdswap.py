@@ -55,9 +55,6 @@ def main():
     for name, st in (("pdswap", st_pd), ("static", st_st)):
         print(f"{name:8s} {st.decode_tokens:10d} {st.decode_tput():12.1f} "
               f"{st.swaps:6d} {st.t_prefill:10.2f}")
-    hid = [t.hidden_fraction for t in st_pd.swap_timings if t.t_total_overlapped]
-    if hid:
-        print(f"swap overlap hid {100*float(np.mean(hid)):.0f}% of the relayout latency")
     print(f"greedy outputs identical across engines: {same}")
     assert same, "PD-Swap must be bit-identical to the static engine"
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -60,6 +61,15 @@ class PhaseProgram:
         self.lowered = self.fn.lower(*args)
         self.compiled = self.lowered.compile()
         return self.compiled
+
+
+def program_name(key: str, phase: str = "") -> str:
+    """The jitted function's name for the program registered under ``key``:
+    the key with every run of other characters than letters, digits and
+    ``_`` made one ``_``, led by ``swap_`` for the swap phase's programs
+    (``relayout:1x512->4160`` -> ``swap_relayout_1x512_4160``)."""
+    name = re.sub(r"\W+", "_", key).strip("_")
+    return f"swap_{name}" if phase == "swap" and not name.startswith("swap") else name
 
 
 def _mesh_axes(mesh: Optional[Mesh]) -> MeshAxes:
@@ -132,10 +142,17 @@ class PhaseEngine:
         ONE construction path for phase programs: ``donate`` is both the
         ``jax.jit(donate_argnums=...)`` argument and the program's declared
         donation, so the registry the analysis pass audits reflects what the
-        compiler was actually told."""
+        compiler was actually told.  The jitted function is named
+        ``program_name(key, phase)``, so the compiled module (and a profiler
+        trace) reads ``jit_decode_4x9216``, not ``jit_fn``."""
+        @functools.wraps(fn)
+        def named(*args):
+            return fn(*args)
+
+        named.__name__ = named.__qualname__ = program_name(key, phase)
         prog = PhaseProgram(
             key,
-            self._jit(fn, in_shardings=in_shardings,
+            self._jit(named, in_shardings=in_shardings,
                       out_shardings=out_shardings, donate=donate),
             donate_argnums=tuple(donate),
             phase=phase,

@@ -13,9 +13,11 @@ back-to-back on independent buffers; the relayout's collectives overlap the
 tail's compute).  Decode starts only after both complete — the paper's
 conservative correctness rule.
 
-``SwapTiming`` records both the measured wall-clock on this host and the
-modeled v5e latencies from the roofline reports, so benchmarks can report
-the overlap win on target hardware (Fig. 5 analogue).
+``SwapTiming`` records the measured wall-clock on this host.  Only
+``measure_both`` (one serialized and one overlapped run) gives
+``hidden_fraction`` a meaning; an overlapped run alone never times the
+relayout by itself.  In a serving engine the swap's device time is the
+``swap_relayout`` program's in a profiler trace, under the ``swap`` span.
 """
 from __future__ import annotations
 
@@ -24,6 +26,8 @@ import time
 from typing import Any, Callable, Optional, Tuple
 
 import jax
+
+from repro.obs.trace import TRACER
 
 
 @dataclasses.dataclass
@@ -57,7 +61,6 @@ class SwapAggregates:
 
     count: int = 0
     sum_cost: float = 0.0  # exposed (decode-visible) swap latency
-    sum_hidden_fraction: float = 0.0
 
     @staticmethod
     def exposed_cost(t: SwapTiming) -> float:
@@ -71,19 +74,20 @@ class SwapAggregates:
     def update(self, t: SwapTiming) -> None:
         self.count += 1
         self.sum_cost += self.exposed_cost(t)
-        self.sum_hidden_fraction += t.hidden_fraction
 
     @property
     def mean_cost(self) -> float:
         return self.sum_cost / self.count if self.count else 0.0
 
-    @property
-    def mean_hidden_fraction(self) -> float:
-        return self.sum_hidden_fraction / self.count if self.count else 0.0
-
 
 class SwapController:
-    """Temporal PD swap for one engine (the paper's single-RP mode)."""
+    """Temporal PD swap for one engine (the paper's single-RP mode).
+
+    ``wait`` blocks on a result (``jax.block_until_ready`` by default; the
+    serving engine passes one that also counts the time).  Traced, a run is
+    ``prefill.dispatch`` (the body), a wait, and ``swap``: the relayout's
+    dispatch and the waits that end it (with overlap, the tail's dispatch
+    and wait too)."""
 
     def __init__(
         self,
@@ -92,11 +96,13 @@ class SwapController:
         kv_relayout: Callable,
         *,
         conservative: bool = True,
+        wait: Callable = jax.block_until_ready,
     ):
         self.prefill_body = prefill_body
         self.prefill_tail = prefill_tail
         self.kv_relayout = kv_relayout
         self.conservative = conservative
+        self.wait = wait
 
     def prefill_and_swap(
         self, params, tokens, *, overlap: bool = True
@@ -107,31 +113,36 @@ class SwapController:
         Fig. 5 benchmark measures against).
         """
         timing = SwapTiming()
+        wait = self.wait
         t0 = time.perf_counter()
-        x_mid, kv = self.prefill_body(params, tokens)
-        jax.block_until_ready(x_mid)
+        with TRACER.span("prefill.dispatch"):
+            x_mid, kv = self.prefill_body(params, tokens)
+        wait(x_mid)
         timing.t_body = time.perf_counter() - t0
 
         if overlap:
             # Dispatch the swap FIRST: it depends only on `kv`, so it can run
             # concurrently with the tail (async dispatch; on TPU the relayout
             # collectives overlap the tail's FFN compute).
-            t1 = time.perf_counter()
-            cache = self.kv_relayout(kv)
-            logits = self.prefill_tail(params, x_mid)
-            jax.block_until_ready(logits)
-            timing.t_tail = time.perf_counter() - t1
-            jax.block_until_ready(cache)  # conservative: decode waits for swap
+            with TRACER.span("swap"):
+                t1 = time.perf_counter()
+                cache = self.kv_relayout(kv)
+                logits = self.prefill_tail(params, x_mid)
+                wait(logits)
+                timing.t_tail = time.perf_counter() - t1
+                wait(cache)  # conservative: decode waits for swap
             timing.t_total_overlapped = time.perf_counter() - t0
         else:
             t1 = time.perf_counter()
-            logits = self.prefill_tail(params, x_mid)
-            jax.block_until_ready(logits)
+            with TRACER.span("prefill.dispatch"):
+                logits = self.prefill_tail(params, x_mid)
+            wait(logits)
             timing.t_tail = time.perf_counter() - t1
-            t2 = time.perf_counter()
-            cache = self.kv_relayout(kv)
-            jax.block_until_ready(cache)
-            timing.t_relayout = time.perf_counter() - t2
+            with TRACER.span("swap"):
+                t2 = time.perf_counter()
+                cache = self.kv_relayout(kv)
+                wait(cache)
+                timing.t_relayout = time.perf_counter() - t2
             timing.t_total_serialized = time.perf_counter() - t0
         return logits, cache, timing
 

@@ -129,6 +129,7 @@ def _chunked_attention(
     return out[:, :, :sq]
 
 
+@jax.named_scope("attention")
 def attention_prefill(
     params: dict,
     x: jax.Array,  # (B, S, d)
@@ -186,6 +187,7 @@ def attention_prefill(
     return y, (kt, vt)
 
 
+@jax.named_scope("attention")
 def attention_prefill_chunk(
     params: dict,
     x: jax.Array,  # (B, C, d) — one chunk of the prompt
@@ -260,6 +262,7 @@ def attention_prefill_chunk(
     return y, (kt, vt)
 
 
+@jax.named_scope("attention")
 def attention_verify(
     params: dict,
     x: jax.Array,  # (B, W, d) — per slot: [last sampled token, draft_1..draft_k]
@@ -478,6 +481,7 @@ def scatter_new_scales(buf: jax.Array, new: jax.Array, lengths: jax.Array) -> ja
     return jax.vmap(upd_one)(buf, newb, idx)
 
 
+@jax.named_scope("kv_write")
 def scatter_new_tokens_q(buf, new: jax.Array, lengths: jax.Array):
     """``scatter_new_tokens`` generalized to a possibly-quantized cache leaf:
     quantize-on-write of the fresh token rows (payload + scale plane), so
@@ -511,6 +515,7 @@ def scatter_new_scales_paged(
     return pages.at[page, :, :, off].set(newb, mode="drop")
 
 
+@jax.named_scope("kv_write")
 def scatter_new_tokens_paged_q(pages, new: jax.Array, block_tables: jax.Array, lengths: jax.Array):
     """``scatter_new_tokens_paged`` generalized to a possibly-quantized page
     pool leaf — quantize-on-write into the current page (see
@@ -537,6 +542,7 @@ def write_prefill_scales(
     return pages.at[page_ids].set(sb.astype(pages.dtype), mode="drop")
 
 
+@jax.named_scope("kv_write")
 def write_prefill_pages_q(pages, kv: jax.Array, page_ids: jax.Array, *, block_size: int):
     """``write_prefill_pages`` generalized to a possibly-quantized pool leaf:
     the paged swap becomes quantize-on-write (per-token-per-head scales),
@@ -573,6 +579,7 @@ def write_chunk_scales(buf: jax.Array, new: jax.Array, slot, start) -> jax.Array
     return jax.lax.dynamic_update_slice(buf, newb, (slot, 0, 0, start))
 
 
+@jax.named_scope("kv_write")
 def write_chunk_kv_q(buf, new: jax.Array, slot, start):
     """``write_chunk_kv`` generalized to a possibly-quantized cache leaf:
     quantize-on-write of the chunk rows (payload + scale plane).  Per-token
@@ -628,6 +635,7 @@ def scatter_verify_scales(
     return buf.at[bidx, :, :, pos].set(newb, mode="drop")
 
 
+@jax.named_scope("kv_write")
 def scatter_verify_tokens_q(buf, new: jax.Array, lengths: jax.Array, n_tokens: jax.Array):
     """``scatter_verify_tokens`` generalized to a possibly-quantized cache
     leaf: quantize-on-write of the block rows (payload + per-(layer, head,
@@ -688,6 +696,7 @@ def scatter_verify_scales_paged(
     return pages.at[page, :, :, off].set(newb, mode="drop")
 
 
+@jax.named_scope("kv_write")
 def scatter_verify_tokens_paged_q(
     pages, new: jax.Array, block_tables: jax.Array,
     lengths: jax.Array, n_tokens: jax.Array
@@ -733,6 +742,7 @@ def _merge_new_token(
     return out
 
 
+@jax.named_scope("attention")
 def attention_decode(
     params: dict,
     x: jax.Array,  # (B, 1, d)
@@ -807,6 +817,7 @@ def _decode_new_token(params, x, lengths, cfg, window, attend_cache):
     return y, KVCache(k_new, v_new)
 
 
+@jax.named_scope("attention")
 def attention_decode_paged(
     params: dict,
     x: jax.Array,  # (B, 1, d)

@@ -39,6 +39,7 @@ def _act_fake_quant_ste(x: jax.Array) -> jax.Array:
     return x + jax.lax.stop_gradient(deq - x)
 
 
+@jax.named_scope("linear")
 def linear_apply(
     params: dict,
     x: jax.Array,
@@ -59,10 +60,12 @@ def linear_apply(
             y = y.astype(x.dtype)
         else:
             # unconverted ternary inference: quantize on the fly (slow path)
-            x_q, s = quantize_activations_int8(x)
+            with jax.named_scope("act_quant"):
+                x_q, s = quantize_activations_int8(x)
             from repro.quant.ternary import ternary_quantize
 
-            w_q, beta = ternary_quantize(w.astype(jnp.float32))
+            with jax.named_scope("weight_quant"):
+                w_q, beta = ternary_quantize(w.astype(jnp.float32))
             acc = jax.lax.dot_general(
                 x_q.reshape(-1, x.shape[-1]), w_q, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32,

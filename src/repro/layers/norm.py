@@ -17,6 +17,7 @@ def norm_init(kind: str, d: int, dtype=jnp.float32) -> dict:
     return rmsnorm_init(d, dtype) if kind == "rmsnorm" else layernorm_init(d, dtype)
 
 
+@jax.named_scope("norm")
 def apply_norm(params: dict, x: jax.Array, kind: str = "rmsnorm", eps: float = 1e-5) -> jax.Array:
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":

@@ -68,6 +68,7 @@ def init(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> dict:
     return params
 
 
+@jax.named_scope("lm_head")
 def _logits(params, x, cfg: ModelConfig, pctx: PartitionCtx) -> jax.Array:
     x = apply_norm(params["ln_f"], x, cfg.norm, cfg.norm_eps)
     head = params["emb"].T if cfg.tie_embeddings else params["lm_head"]
@@ -75,6 +76,7 @@ def _logits(params, x, cfg: ModelConfig, pctx: PartitionCtx) -> jax.Array:
     return pctx.shard(logits, "batch", "seq", "vocab")
 
 
+@jax.named_scope("embed")
 def _embed(params, tokens, cfg, pctx):
     x = params["emb"][tokens]
     return pctx.shard(x, "batch", "seq", "embed")
@@ -268,10 +270,11 @@ def _prefill_chunk_body(params, tokens, prefix, prefix_len, cfg, pctx,
 
     x, (tok_k, tok_v) = jax.lax.scan(body, x, (params["layers"], jnp.arange(cfg.num_layers)))
     start = (0, 0, 0, prefix_len, 0)
-    new_prefix = KVCache(
-        jax.lax.dynamic_update_slice(prefix.k, tok_k.astype(prefix.k.dtype), start),
-        jax.lax.dynamic_update_slice(prefix.v, tok_v.astype(prefix.v.dtype), start),
-    )
+    with jax.named_scope("kv_write"):
+        new_prefix = KVCache(
+            jax.lax.dynamic_update_slice(prefix.k, tok_k.astype(prefix.k.dtype), start),
+            jax.lax.dynamic_update_slice(prefix.v, tok_v.astype(prefix.v.dtype), start),
+        )
     return x, tok_k, tok_v, new_prefix
 
 

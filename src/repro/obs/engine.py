@@ -63,6 +63,11 @@ _STAT_COUNTERS = (
     ("t_prefill", "repro_prefill_seconds_total", "Wall time in prefill compute"),
     ("t_decode", "repro_decode_seconds_total", "Wall time in decode/verify rounds"),
     ("t_replay", "repro_replay_seconds_total", "Wall time replaying preemption restarts"),
+    ("steps", "repro_engine_steps_total", "EngineCore.step() calls"),
+    ("t_step", "repro_engine_step_seconds_total", "Wall time inside EngineCore.step()"),
+    ("t_wait", "repro_engine_wait_seconds_total",
+     "Wall time inside the engine's block_until_ready calls; host time per "
+     "step is (step - wait seconds) / steps"),
 )
 
 _LATENCY_HISTOGRAMS = (
@@ -136,9 +141,6 @@ def engine_registry(core, frontend=None) -> MetricsRegistry:
     reg.gauge("repro_swap_exposed_cost_seconds",
               "Mean decode-visible swap latency",
               fn=lambda: core.stats.swap_agg.mean_cost)
-    reg.gauge("repro_swap_hidden_fraction",
-              "Mean fraction of swap latency hidden under the prefill tail",
-              fn=lambda: core.stats.swap_agg.mean_hidden_fraction)
     for kind in ("allocated", "peak_in_use", "payload"):
         reg.gauge("repro_kv_cache_bytes", "KV cache memory accounting",
                   labels={"kind": kind},

@@ -3,12 +3,20 @@
 The serving stack is instrumented at every phase boundary the paper's
 timeline argument cares about — request lifecycle events (submit → admit →
 prefill chunk[i] → KV handoff → decode round → spec verify → preempt /
-replay → shed / abort / finish) and engine spans (swap, chunk compute,
-decode quantum, handoff transfer).  Instrumentation sites call the module
-singleton ``TRACER``; when tracing is disabled every call is a single
-attribute check (hot paths guard with ``if TRACER.enabled`` so the disabled
-cost is one branch, CI-gated < 3 % on the decode loop by
-``benchmarks/tracing_overhead.py``).
+replay → shed / abort / finish) and engine spans (engine step, scheduling,
+prefill and chunk dispatch and wait, swap, the decode round's prepare /
+dispatch / wait / outputs phases).  Instrumentation sites call the module
+singleton ``TRACER``; when tracing is disabled ``span()`` is one attribute
+check that returns a shared no-op context (no annotation is built), and
+``instant()`` / ``finish()`` return at the same check — CI-gated < 3 % on
+the decode loop by ``benchmarks/tracing_overhead.py``.
+
+While enabled, every live span (``span()``) also enters a
+``jax.profiler.TraceAnnotation`` of the same name with the span's
+arguments, so a profiler trace taken meanwhile holds the engine's spans on
+its host plane, on the device trace's clock: operators capture one with
+``TRACER.enable()`` inside ``jax.profiler.trace(...)``.  ``complete()``
+records a span timed elsewhere; it reaches the ring buffer only.
 
 Events land in a ``deque(maxlen=capacity)`` — a long serving run can trace
 forever and keep only the most recent window; ``dropped`` counts evictions.
@@ -32,6 +40,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class _NullSpan:
     """No-op context manager returned by ``span()`` when tracing is off."""
@@ -49,7 +59,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_lane", "_args", "_t0")
+    """A live span: the ring-buffer record and a profiler annotation of the
+    same name and arguments, entered and left together."""
+
+    __slots__ = ("_tr", "_name", "_lane", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, lane: Optional[str], args):
         self._tr = tr
@@ -58,12 +71,15 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tr.complete(self._name, self._t0, time.perf_counter(),
-                          lane=self._lane, **(self._args or {}))
+        t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        self._tr.complete(self._name, self._t0, t1, lane=self._lane, **self._args)
         return False
 
 
@@ -131,9 +147,9 @@ class Tracer:
     def complete(self, name: str, t0: float, t1: float,
                  lane: Optional[str] = None, **args) -> None:
         """Record a complete span from ``perf_counter`` stamps the caller
-        already took — the hot-path form: sites that time themselves anyway
-        (decode round, prefill chunk) pay only this call when enabled and
-        one ``if TRACER.enabled`` branch when not."""
+        already took.  It reaches the ring buffer only: a profiler
+        annotation cannot be entered after the fact, so sites that belong
+        on the profiler's clock use ``span()``."""
         if not self.enabled:
             return
         self._emitted += 1
@@ -142,7 +158,9 @@ class Tracer:
              lane or threading.current_thread().name, args or None))
 
     def span(self, name: str, lane: Optional[str] = None, **args):
-        """Context-manager span for cold paths."""
+        """Context-manager span, recorded in the ring buffer and entered as
+        a ``jax.profiler.TraceAnnotation``.  Disabled: one attribute check
+        and a shared no-op context."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, lane, args)

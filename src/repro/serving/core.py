@@ -202,6 +202,12 @@ class EngineStats:
     tenant_queue_wait: Dict[str, LatencyStat] = dataclasses.field(default_factory=dict)
     aborts: int = 0  # requests cancelled mid-flight or while queued
     sheds: int = 0  # queued requests dropped by SLO admission control
+    # host overhead: step() calls, wall time inside them, and the part of it
+    # spent in the engine's own block_until_ready calls (decode, verify,
+    # chunk, prefill, replay) — t_step - t_wait is the host's time per step
+    steps: int = 0
+    t_step: float = 0.0
+    t_wait: float = 0.0
 
     def decode_tput(self) -> float:
         return self.decode_tokens / self.t_decode if self.t_decode else 0.0
@@ -238,7 +244,7 @@ class EngineStats:
             "prefix_hits", "prefix_misses", "prefix_hit_tokens",
             "preemptions", "admission_blocks", "replayed_tokens", "t_replay",
             "draft_tokens", "accepted_tokens", "verify_rounds", "slot_rounds",
-            "decode_ctx_tokens", "aborts", "sheds",
+            "decode_ctx_tokens", "aborts", "sheds", "steps", "t_step", "t_wait",
         )
         snap = {k: getattr(self, k) for k in counters}
         snap.update(
@@ -249,13 +255,24 @@ class EngineStats:
             swap_agg={
                 "count": self.swap_agg.count,
                 "mean_exposed_cost_s": self.swap_agg.mean_cost,
-                "mean_hidden_fraction": self.swap_agg.mean_hidden_fraction,
             },
             queue_wait_s=self.queue_wait.snapshot(),
             ttft_s=self.ttft.snapshot(),
             itl_s=self.itl.snapshot(),
         )
         return snap
+
+
+def timed_wait(x, stats: EngineStats, span: str) -> float:
+    """``jax.block_until_ready(x)`` inside the trace span ``span``, its wall
+    time counted in ``stats.t_wait``; returns the ``perf_counter`` stamp
+    taken when the wait ended."""
+    t0 = time.perf_counter()  # analysis: allow(det:wallclock) — wait wall time feeds t_wait stats and a trace span only
+    with TRACER.span(span):
+        jax.block_until_ready(x)
+    t1 = time.perf_counter()  # analysis: allow(det:wallclock) — wait wall time feeds t_wait stats and a trace span only
+    stats.t_wait += t1 - t0
+    return t1
 
 
 class ModelRunner:
@@ -662,35 +679,35 @@ class ModelRunner:
         for the final chunk).  The install is fused into the chunk program,
         so there is no separate relayout swap to overlap: the fabric flips
         back to decode right after each chunk."""
-        padded = self.chunk_bucket(size, start)
-        prog = self.chunk_prog(padded, self.prefix_width(start))
-        buf = np.zeros((padded,), np.int32)
-        buf[:size] = np.asarray(req.prompt[start : start + size], np.int32)
-        tokens = jnp.asarray(buf[None])
-        t0 = time.perf_counter()  # analysis: allow(det:wallclock) — chunk wall time feeds t_prefill/t_replay stats and a trace span only
-        if self.cache_layout == "paged":
-            bs = self.block_size
-            # start is page-aligned (chunk % bs == 0); prefix-cache hits and
-            # padding pages arrive as the OOB skip sentinel and are dropped
-            ids = self.paged.page_ids_for_write(
-                match, padded // bs, first_page=start // bs)
-            logits, self.paged.kv, self.chunk_prefix = prog.fn(
-                self.params, tokens, self.paged.kv, self.chunk_prefix,
-                ids, start, size - 1)
-        else:
-            logits, self.cache, self.chunk_prefix = prog.fn(
-                self.params, tokens, self.cache, self.chunk_prefix, slot,
-                start, size - 1)
-        jax.block_until_ready(logits)
-        t1 = time.perf_counter()  # analysis: allow(det:wallclock) — chunk wall time feeds t_prefill/t_replay stats and a trace span only
+        with TRACER.span("prefill.chunk", request_id=req.request_id,
+                         start=start, size=size):
+            padded = self.chunk_bucket(size, start)
+            prog = self.chunk_prog(padded, self.prefix_width(start))
+            buf = np.zeros((padded,), np.int32)
+            buf[:size] = np.asarray(req.prompt[start : start + size], np.int32)
+            tokens = jnp.asarray(buf[None])
+            t0 = time.perf_counter()  # analysis: allow(det:wallclock) — chunk wall time feeds t_prefill/t_replay stats only
+            with TRACER.span("prefill.dispatch"):
+                if self.cache_layout == "paged":
+                    bs = self.block_size
+                    # start is page-aligned (chunk % bs == 0); prefix-cache
+                    # hits and padding pages arrive as the OOB skip sentinel
+                    # and are dropped
+                    ids = self.paged.page_ids_for_write(
+                        match, padded // bs, first_page=start // bs)
+                    logits, self.paged.kv, self.chunk_prefix = prog.fn(
+                        self.params, tokens, self.paged.kv, self.chunk_prefix,
+                        ids, start, size - 1)
+                else:
+                    logits, self.cache, self.chunk_prefix = prog.fn(
+                        self.params, tokens, self.cache, self.chunk_prefix, slot,
+                        start, size - 1)
+            t1 = timed_wait(logits, stats, "prefill.wait")
         if restarted:  # restart re-prefill is recompute overhead, not load
             stats.t_replay += t1 - t0
         else:
             stats.t_prefill += t1 - t0
         stats.prefill_chunks += 1
-        if TRACER.enabled:
-            TRACER.complete("prefill.chunk", t0, t1,
-                            request_id=req.request_id, start=start, size=size)
         return logits
 
     # ------------------------------------------------------------- prefill --
@@ -711,6 +728,13 @@ class ModelRunner:
         Returns the prompt's last-token logits, shape (1, V).  Raises
         ``PoolExhausted`` (after full rollback) when the paged pool cannot
         hold the prompt."""
+        with TRACER.span("prefill", request_id=req.request_id,
+                         tokens=len(req.prompt), resuming=resuming):
+            return self._prefill(req, slot, resuming, stats)
+
+    def _prefill(self, req: Request, slot: int, resuming: bool, stats: EngineStats):
+        """``prefill`` inside its span (the disaggregated runner replaces
+        this body)."""
         tokens_np = np.asarray(req.prompt, np.int32)
         n = len(tokens_np)
         bucket = self.bucket(n)
@@ -756,19 +780,17 @@ class ModelRunner:
                 progs["body"].fn,
                 lambda p, x: progs["tail"].fn(p, x, last_pos),
                 swap_write,
+                wait=lambda x: timed_wait(x, stats, "prefill.wait"),
             )
             logits, _, timing = ctl.prefill_and_swap(
                 self.params, tokens, overlap=self.overlap
             )
             if not resuming:
                 stats.record_swap(timing)
-            if TRACER.enabled:
-                TRACER.instant("swap", request_id=req.request_id,
-                               t_relayout=timing.t_relayout,
-                               hidden_fraction=timing.hidden_fraction)
         else:
-            logits, kv = progs["full"].fn(self.params, tokens, last_pos)
-            swap_write(kv)
+            with TRACER.span("prefill.dispatch"):
+                logits, kv = progs["full"].fn(self.params, tokens, last_pos)
+                swap_write(kv)
         # restarts are recompute overhead, not offered load: their prefill
         # time joins t_replay and they never re-count prefill_tokens/swaps
         t1 = time.perf_counter()  # analysis: allow(det:wallclock) — prefill wall time feeds t_prefill/t_replay stats only
@@ -777,9 +799,6 @@ class ModelRunner:
         else:
             stats.t_prefill += t1 - t0
             stats.prefill_tokens += n
-        if TRACER.enabled:
-            TRACER.complete("prefill", t0, t1, request_id=req.request_id,
-                            tokens=n, resuming=resuming)
 
         if self.cache_layout == "paged":
             self.paged.register_prompt_pages(match)
@@ -953,9 +972,15 @@ class ModelRunner:
         Replay wall time lands in ``stats.t_replay`` — blocking here keeps
         the async-dispatched replay compute from leaking into the next
         decode round's ``t_decode`` (it would skew decode_tput)."""
+        with TRACER.span("replay", request_id=req.request_id,
+                         tokens=max(len(req.out_tokens) - 1, 0)):
+            return self._replay(slot, req, stats)
+
+    def _replay(self, slot: int, req: Request, stats: EngineStats) -> bool:
+        """``replay`` inside its span."""
         p = len(req.prompt)
         n_slots = self.slots.n_slots
-        t0 = time.perf_counter()  # analysis: allow(det:wallclock) — replay wall time feeds t_replay stats and a trace span only
+        t0 = time.perf_counter()  # analysis: allow(det:wallclock) — replay wall time feeds t_replay stats only
         for j, tok in enumerate(req.out_tokens[:-1]):
             pos = p + j
             try:
@@ -973,12 +998,8 @@ class ModelRunner:
                 jnp.asarray(lengths),
             )
             stats.replayed_tokens += 1
-        jax.block_until_ready(jax.tree.leaves(self.paged.kv))
-        t1 = time.perf_counter()  # analysis: allow(det:wallclock) — replay wall time feeds t_replay stats and a trace span only
+        t1 = timed_wait(jax.tree.leaves(self.paged.kv), stats, "replay.wait")
         stats.t_replay += t1 - t0
-        if TRACER.enabled:
-            TRACER.complete("replay", t0, t1, request_id=req.request_id,
-                            tokens=max(len(req.out_tokens) - 1, 0))
         return True
 
     def release(self, slot: int) -> None:
@@ -1298,38 +1319,60 @@ class EngineCore:
         get a token between every pair of chunks instead of stalling for
         the whole burst.  Returns every streaming output the quantum
         produced."""
-        t_step0 = time.perf_counter() if TRACER.enabled else 0.0  # analysis: allow(det:wallclock) — trace-span stamp, recorded only while tracing
+        t0 = time.perf_counter()  # analysis: allow(det:wallclock) — step wall time feeds t_step stats only
+        with TRACER.span("engine.step"):
+            outs = self._step()
+        stats = self.stats
+        stats.steps += 1
+        stats.t_step += time.perf_counter() - t0  # analysis: allow(det:wallclock) — step wall time feeds t_step stats only
+        return outs
+
+    def _shed(self) -> List[RequestOutput]:
+        """SLO admission control: a policy that knows the TTFT deadline may
+        shed queue heads that can no longer meet it.  A doomed request
+        counts against goodput whether it is served late or dropped — but
+        serving it also queues everyone BEHIND it past their deadlines, so
+        shedding converts one unavoidable miss into capacity for requests
+        that can still hit their targets.  Only policies exposing
+        ``should_shed`` participate; the static policies serve every
+        admitted request, late or not."""
+        sched = self.scheduler
         outs: List[RequestOutput] = []
-        sched, runner = self.scheduler, self.runner
-        # SLO admission control: a policy that knows the TTFT deadline may
-        # shed queue heads that can no longer meet it.  A doomed request
-        # counts against goodput whether it is served late or dropped —
-        # but serving it also queues everyone BEHIND it past their
-        # deadlines, so shedding converts one unavoidable miss into
-        # capacity for requests that can still hit their targets.  Only
-        # policies exposing ``should_shed`` participate; the static
-        # policies serve every admitted request, late or not.
         shed = getattr(sched.policy, "should_shed", None)
-        if shed is not None:
-            now = time.perf_counter()  # analysis: allow(det:wallclock) — shed deadline check paces admission (drop-or-serve), never token values
-            while sched.queue:
-                head = sched.queue[0]
-                if head.out_tokens or getattr(head, "preempted", False):
-                    # a preempted / partially-served request is in-flight
-                    # state awaiting replay, not a new admission — dropping
-                    # it is not admission control
-                    break
-                wait = (now - head.arrival_time_s) if head.arrival_time_s else 0.0
-                if not shed(wait):
-                    break
-                sched.queue.popleft()
-                self.stats.sheds += 1
-                if TRACER.enabled:
-                    TRACER.instant("req.shed", request_id=head.request_id,
-                                   wait_s=wait)
-                outs.append(self.out_proc.finalize_dropped(head, "shed"))
-                self.finished[head.request_id] = head
-        if runner.prefill_chunk is not None:
+        if shed is None:
+            return outs
+        now = time.perf_counter()  # analysis: allow(det:wallclock) — shed deadline check paces admission (drop-or-serve), never token values
+        while sched.queue:
+            head = sched.queue[0]
+            if head.out_tokens or getattr(head, "preempted", False):
+                # a preempted / partially-served request is in-flight
+                # state awaiting replay, not a new admission — dropping
+                # it is not admission control
+                break
+            wait = (now - head.arrival_time_s) if head.arrival_time_s else 0.0
+            if not shed(wait):
+                break
+            sched.queue.popleft()
+            self.stats.sheds += 1
+            if TRACER.enabled:
+                TRACER.instant("req.shed", request_id=head.request_id,
+                               wait_s=wait)
+            outs.append(self.out_proc.finalize_dropped(head, "shed"))
+            self.finished[head.request_id] = head
+        return outs
+
+    def _step(self) -> List[RequestOutput]:
+        sched, runner = self.scheduler, self.runner
+        chunked = runner.prefill_chunk is not None
+        with TRACER.span("engine.schedule"):
+            outs = self._shed()
+            if chunked:
+                prog, admitted = self._schedule_chunk()
+                outs.extend(admitted)
+            else:
+                burst = (bool(sched.queue) and bool(runner.slots.free_slots())
+                         and sched.enter_prefill_phase(self.stats))
+        if chunked:
             # An SLO-aware policy can widen the EFFECTIVE prefill chunk by
             # granting several chunk quanta back to back before the decode
             # round (prefill_quanta > 1 when observed ITL has budget slack,
@@ -1338,11 +1381,8 @@ class EngineCore:
             # exactly one quantum — the PR-4 behavior.
             pq = getattr(sched.policy, "prefill_quanta", None)
             ran = 0
-            while True:
-                before = self.stats.prefill_chunks
-                outs.extend(self._chunked_prefill_quantum())
-                if self.stats.prefill_chunks == before:
-                    break  # deferred, blocked, or no prefill work pending
+            while prog is not None:  # None: deferred, blocked, or no work
+                outs.extend(self._advance_chunk(prog))
                 ran += 1
                 # re-consult AFTER each executed quantum: the policy's view
                 # was refreshed by that quantum's should_prefill, so the
@@ -1352,7 +1392,10 @@ class EngineCore:
                 # "empty set" stalls the new stream for the full width)
                 if pq is None or ran >= max(1, int(pq())):
                     break
-        elif sched.queue and runner.slots.free_slots() and sched.enter_prefill_phase(self.stats):
+                with TRACER.span("engine.schedule"):
+                    prog, admitted = self._schedule_chunk()
+                outs.extend(admitted)
+        elif burst:
             admitted = 0
             while sched.queue and runner.slots.free_slots():
                 ok, out = self._admit_one(sched.queue.popleft())
@@ -1369,9 +1412,6 @@ class EngineCore:
             outs.extend(self._decode_round())
         if not self.has_unfinished():
             sched.policy.reset()
-        if TRACER.enabled and t_step0:
-            TRACER.complete("engine.step", t_step0, time.perf_counter(),  # analysis: allow(det:wallclock) — trace-span stamp, recorded only while tracing
-                            outputs=len(outs))
         return outs
 
     def _unblock_admission_or_raise(self) -> None:
@@ -1400,43 +1440,45 @@ class EngineCore:
     def _pending_chunks(self) -> int:
         return sum(p.remaining_chunks for p in self._prefilling.values())
 
-    def _chunked_prefill_quantum(self) -> List[RequestOutput]:
-        """At most one chunk of pending prefill this quantum: continue the
+    def _schedule_chunk(self):
+        """At most one chunk of pending prefill per quantum: continue the
         oldest partially-prefilled request, or — none pending — admit the
-        queue head and run its first chunk.  Both are policy-gated (the
+        queue head (its first chunk runs next).  Both are policy-gated (the
         view carries the pending-chunk count), and each chunk executed is
-        one fabric flip (``prefill_bursts``)."""
+        one fabric flip (``prefill_bursts``).  Returns ``(prefill progress
+        whose next chunk runs now, or None; outputs the admission
+        produced)``."""
         sched, runner = self.scheduler, self.runner
         if self._prefilling:
             if not sched.enter_prefill_phase(
                     self.stats, pending_chunks=self._pending_chunks()):
-                return []
-            slot = next(iter(self._prefilling))
-            return self._advance_chunk(self._prefilling[slot])
+                return None, []
+            return next(iter(self._prefilling.values())), []
         if not (sched.queue and runner.slots.free_slots()):
-            return []
+            return None, []
         if not sched.enter_prefill_phase(self.stats):
-            return []
-        ok, outs = self._admit_one_chunked(sched.queue.popleft())
+            return None, []
+        ok, prog, outs = self._admit_one_chunked(sched.queue.popleft())
         if not ok and not sched.inflight:
             self._unblock_admission_or_raise()
-        return outs
+        return prog, outs
 
     def _admit_one_chunked(self, req: Request):
         """Chunked admission: reserve the slot (and, paged, ALL prompt
-        pages — chunk writes then land in a stable page plan), then run the
-        first chunk.  Returns ``(ok, outputs)`` with the same blocked-
-        admission contract as ``_admit_one``."""
+        pages — chunk writes then land in a stable page plan).  Returns
+        ``(ok, prefill progress or None, outputs)`` with the same blocked-
+        admission contract as ``_admit_one``; the caller runs the first
+        chunk."""
         runner, stats = self.runner, self.stats
         out = self._finish_resumed_at_budget(req)
         if out is not None:
-            return True, [out]
+            return True, None, [out]
         resuming = req.preempted and bool(req.out_tokens)
         restarted = req.preempted  # mid-prefill evictions restart with no tokens
 
         if runner.cache_layout == "paged" and resuming and not runner.restart_headroom_ok(req):
             self._block_admission(req)
-            return False, []
+            return False, None, []
 
         slot = runner.slots.assign(req.request_id, len(req.prompt), req.max_new)
         runner.set_slot_sampling(slot, req)
@@ -1446,7 +1488,7 @@ class EngineCore:
                 match = runner.paged.allocate_prompt(slot, np.asarray(req.prompt, np.int32))
             except PoolExhausted:
                 self._block_admission(req, slot)
-                return False, []
+                return False, None, []
             if not restarted:
                 n_full = len(req.prompt) // runner.block_size
                 stats.prefix_hits += match.cached_pages
@@ -1464,13 +1506,13 @@ class EngineCore:
 
         self._record_admission(req)
         # the shared fp prefix mirror (runner.chunk_prefix) supports exactly
-        # one in-flight chunked prefill — _chunked_prefill_quantum only
+        # one in-flight chunked prefill — _schedule_chunk only
         # admits when none is pending, and this guards the invariant
         assert not self._prefilling, "one chunked prefill in flight at a time"
         prog = PrefillProgress(req, slot, resuming, restarted,
                                sizes=runner.chunk_sizes(len(req.prompt)), match=match)
         self._prefilling[slot] = prog
-        return True, self._advance_chunk(prog)
+        return True, prog, []
 
     def _advance_chunk(self, prog: PrefillProgress) -> List[RequestOutput]:
         """Run one chunk; on the final chunk, finish the prefill (first
@@ -1748,12 +1790,37 @@ class EngineCore:
             drafts = {slot: runner.draft_for(sched.inflight[slot], slot)
                       for slot in sorted(sched.inflight)}
             if any(len(d) for d in drafts.values()):
-                return self._verify_round(drafts)
+                with TRACER.span("decode.verify", batch=len(sched.inflight)):
+                    return self._verify_round(drafts)
+        with TRACER.span("decode.round", batch=len(sched.inflight)):
+            with TRACER.span("decode.prepare"):
+                active, lengths = self._decode_inputs()
+            if not active:
+                return []
+            t0 = time.perf_counter()  # analysis: allow(det:wallclock) — decode-round wall time feeds t_decode stats only
+            with TRACER.span("decode.dispatch"):
+                logits = runner.decode_logits(lengths)
+                next_tokens = runner.sample_batch(logits, sched.inflight)
+            t1 = timed_wait(next_tokens, stats, "decode.wait")
+            stats.t_decode += t1 - t0
+            stats.decode_rounds += 1
+            stats.decode_tokens += len(active)
+
+            stats.slot_rounds += len(active)
+            stats.decode_ctx_tokens += int(
+                sum(runner.slots.slots[i].length for i in active))
+            with TRACER.span("decode.outputs"):
+                return self._decode_outputs(active, next_tokens)
+
+    def _decode_inputs(self):
+        """Page growth and the lengths operand of a plain decode round:
+        ``(active slots, lengths)``."""
+        runner = self.runner
         if runner.cache_layout == "paged":
             self._ensure_append_pages()
-        active = sorted(sched.inflight)
+        active = sorted(self.scheduler.inflight)
         if not active:
-            return []
+            return active, None
         if self._prefilling:
             # Mid-prefill slots sit the round out, but the batched decode
             # program still computes (and scatters) a row for them — park
@@ -1766,23 +1833,13 @@ class EngineCore:
             park = 0 if runner.cache_layout == "paged" else runner.max_len
             for slot in self._prefilling:
                 lengths_np[slot] = park
-            lengths = jnp.asarray(lengths_np)
-        else:
-            lengths = runner.slots.lengths_array()
-        t0 = time.perf_counter()  # analysis: allow(det:wallclock) — decode-round wall time feeds t_decode stats only
-        logits = runner.decode_logits(lengths)
-        next_tokens = runner.sample_batch(logits, sched.inflight)
-        jax.block_until_ready(next_tokens)
-        t1 = time.perf_counter()  # analysis: allow(det:wallclock) — decode-round wall time feeds t_decode stats only
-        stats.t_decode += t1 - t0
-        stats.decode_rounds += 1
-        stats.decode_tokens += len(active)
+            return active, jnp.asarray(lengths_np)
+        return active, runner.slots.lengths_array()
 
-        stats.slot_rounds += len(active)
-        stats.decode_ctx_tokens += int(
-            sum(runner.slots.slots[i].length for i in active))
-        if TRACER.enabled:
-            TRACER.complete("decode.round", t0, t1, batch=len(active))
+    def _decode_outputs(self, active: List[int], next_tokens) -> List[RequestOutput]:
+        """Read a plain round's tokens back and stream them: per-slot output
+        handling, slot advance, and release of finished requests."""
+        runner, sched = self.runner, self.scheduler
         next_np = np.asarray(next_tokens)
         outs: List[RequestOutput] = []
         for i in active:
@@ -1831,55 +1888,59 @@ class EngineCore:
         runner, stats, sched = self.runner, self.stats, self.scheduler
         n_slots = runner.slots.n_slots
         w = runner.spec_decode + 1
-        # paged: make each slot's verify span writable (growth + COW;
-        # may preempt victims — including, under pressure, a drafted slot)
-        if runner.cache_layout == "paged":
-            for slot in list(drafts):
-                if slot not in sched.inflight:
-                    continue  # evicted by an earlier slot's growth
-                s = runner.slots.slots[slot]
-                if s.request_id is None:
-                    continue
-                self._grow_slot_span(slot, s.length, len(drafts[slot]) + 1)
-        active = sorted(sched.inflight)
-        if not active:
-            return []
-        last_np = np.array(runner.last_tokens)  # writable copy (np.asarray of
-        # a device array is a read-only view)
-        tokens_np = np.zeros((n_slots, w), np.int32)
-        n_tok_np = np.zeros((n_slots,), np.int32)
-        lengths_np = np.asarray(
-            [s.length for s in runner.slots.slots], np.int32)
-        for slot in active:
-            d = drafts[slot]
-            tokens_np[slot, 0] = last_np[slot]
-            tokens_np[slot, 1 : 1 + len(d)] = d
-            n_tok_np[slot] = 1 + len(d)
-            # satellite invariant: live verify rows stay clear of the
-            # chunked-prefill parked-write row max_len - 1 (draft_for
-            # clamps; this guards any future clamp regression)
-            assert lengths_np[slot] + n_tok_np[slot] - 1 <= runner.max_len - 2, (
-                slot, int(lengths_np[slot]), int(n_tok_np[slot]), runner.max_len)
+        with TRACER.span("decode.prepare"):
+            # paged: make each slot's verify span writable (growth + COW;
+            # may preempt victims — including, under pressure, a drafted slot)
+            if runner.cache_layout == "paged":
+                for slot in list(drafts):
+                    if slot not in sched.inflight:
+                        continue  # evicted by an earlier slot's growth
+                    s = runner.slots.slots[slot]
+                    if s.request_id is None:
+                        continue
+                    self._grow_slot_span(slot, s.length, len(drafts[slot]) + 1)
+            active = sorted(sched.inflight)
+            if not active:
+                return []
+            last_np = np.array(runner.last_tokens)  # writable copy (np.asarray of
+            # a device array is a read-only view)
+            tokens_np = np.zeros((n_slots, w), np.int32)
+            n_tok_np = np.zeros((n_slots,), np.int32)
+            lengths_np = np.asarray(
+                [s.length for s in runner.slots.slots], np.int32)
+            for slot in active:
+                d = drafts[slot]
+                tokens_np[slot, 0] = last_np[slot]
+                tokens_np[slot, 1 : 1 + len(d)] = d
+                n_tok_np[slot] = 1 + len(d)
+                # satellite invariant: live verify rows stay clear of the
+                # chunked-prefill parked-write row max_len - 1 (draft_for
+                # clamps; this guards any future clamp regression)
+                assert lengths_np[slot] + n_tok_np[slot] - 1 <= runner.max_len - 2, (
+                    slot, int(lengths_np[slot]), int(n_tok_np[slot]), runner.max_len)
         # mid-prefill slots sit the round out: n_tokens 0 routes every one
         # of their rows (KV writes) out of bounds, and nothing reads their
         # logits — no parked-write trick needed on this path
         t0 = time.perf_counter()  # analysis: allow(det:wallclock) — verify-round wall time feeds t_decode stats only
-        logits = runner.run_verify(
-            jnp.asarray(tokens_np), jnp.asarray(lengths_np), jnp.asarray(n_tok_np))
-        targets = runner.select_targets(logits, sched.inflight)
-        jax.block_until_ready(targets)
-        t1 = time.perf_counter()  # analysis: allow(det:wallclock) — verify-round wall time feeds t_decode stats only
+        with TRACER.span("decode.dispatch"):
+            logits = runner.run_verify(
+                jnp.asarray(tokens_np), jnp.asarray(lengths_np), jnp.asarray(n_tok_np))
+            targets = runner.select_targets(logits, sched.inflight)
+        t1 = timed_wait(targets, stats, "decode.wait")
         stats.t_decode += t1 - t0
         stats.decode_rounds += 1
         stats.verify_rounds += 1
         stats.slot_rounds += len(active)
         stats.decode_ctx_tokens += int(sum(lengths_np[i] for i in active))
-        if TRACER.enabled:
-            TRACER.complete("decode.verify", t0, t1, batch=len(active),
-                            drafted=int(sum(len(drafts[s]) for s in active)))
+        with TRACER.span("decode.outputs"):
+            return self._verify_outputs(active, drafts, targets, last_np)
 
+    def _verify_outputs(self, active: List[int], drafts, targets,
+                        last_np: np.ndarray) -> List[RequestOutput]:
+        """Accept, stream and roll back one verify round's slots."""
         from repro.core.sampling import accept_length
 
+        runner, stats, sched = self.runner, self.stats, self.scheduler
         targets_np = np.asarray(targets)
         outs: List[RequestOutput] = []
         for slot in active:

@@ -6,7 +6,7 @@ sampling state, preemption replay — all resident on the DECODE mesh (the
 ``mesh`` the base constructor received).  What it overrides is exactly the
 prefill seam:
 
-* ``prefill``: the monolithic body/tail/full programs run on the attached
+* ``_prefill``: the monolithic body/tail/full programs run on the attached
   ``PrefillPool``; the swap payload (contiguous: the relayouted — possibly
   quantized payload+scales — decode-layout tree, built prefill-side; paged:
   the raw fp prefill-layout KV) crosses the ``KVHandoffChannel`` inside
@@ -44,7 +44,7 @@ import numpy as np
 from repro.core.kv_cache import insert_prefill_kv
 from repro.core.swap import SwapController
 from repro.obs.trace import TRACER
-from repro.serving.core import EngineStats, ModelRunner, Request
+from repro.serving.core import EngineStats, ModelRunner, Request, timed_wait
 from repro.serving.disagg.handoff import KVHandoffChannel
 from repro.serving.disagg.prefill_pool import PrefillPool
 from repro.serving.paging import PrefixMatch
@@ -77,9 +77,9 @@ class DisaggRunner(ModelRunner):
 
     # ------------------------------------------------------------- prefill --
 
-    def prefill(self, req: Request, slot: int, resuming: bool, stats: EngineStats):
+    def _prefill(self, req: Request, slot: int, resuming: bool, stats: EngineStats):
         """Monolithic prefill on the prefill pool + handoff + decode-side
-        install — the two-pool mirror of ``ModelRunner.prefill`` (same
+        install — the two-pool mirror of ``ModelRunner._prefill`` (same
         allocation order, same install programs, same stats accounting)."""
         pool, handoff = self.prefill_pool, self.handoff
         tokens_np = np.asarray(req.prompt, np.int32)
@@ -128,6 +128,7 @@ class DisaggRunner(ModelRunner):
                 pprogs["body"].fn,
                 lambda p, x: pprogs["tail"].fn(p, x, last_pos),
                 swap_write,
+                wait=lambda x: timed_wait(x, stats, "prefill.wait"),
             )
             logits, _, timing = ctl.prefill_and_swap(
                 pool.params, tokens, overlap=self.overlap
@@ -135,8 +136,9 @@ class DisaggRunner(ModelRunner):
             if not resuming:
                 stats.record_swap(timing)
         else:
-            logits, kv = pprogs["full"].fn(pool.params, tokens, last_pos)
-            swap_write(kv)
+            with TRACER.span("prefill.dispatch"):
+                logits, kv = pprogs["full"].fn(pool.params, tokens, last_pos)
+                swap_write(kv)
         # first-token logits cross to the decode pool too: the sampler (and
         # any program mixing them with decode-resident operands) must never
         # see prefill-mesh arrays
@@ -147,9 +149,6 @@ class DisaggRunner(ModelRunner):
         else:
             stats.t_prefill += t1 - t0
             stats.prefill_tokens += n
-        if TRACER.enabled:
-            TRACER.complete("prefill", t0, t1, request_id=req.request_id,
-                            tokens=n, resuming=resuming)
 
         if self.cache_layout == "paged":
             self.paged.register_prompt_pages(match)
@@ -169,7 +168,16 @@ class DisaggRunner(ModelRunner):
     ):
         """One chunk computed on the prefill pool, shipped eagerly, install
         deferred (see the module docstring for why deferral is what
-        actually eliminates cross-phase interference)."""
+        actually eliminates cross-phase interference).  Its span is the
+        ENGINE-side window (dispatch + final-chunk drain/sync), distinct
+        from the pool thread's ``prefill.chunk.compute``."""
+        with TRACER.span("prefill.chunk.dispatch", request_id=req.request_id,
+                         start=start, size=size,
+                         final=start + size == len(req.prompt)):
+            return self._run_prefill_chunk(req, slot, start, size, match,
+                                           restarted, stats)
+
+    def _run_prefill_chunk(self, req, slot, start, size, match, restarted, stats):
         pool, handoff = self.prefill_pool, self.handoff
         padded = self.chunk_bucket(size, start)
         prog = pool.chunk_kv_prog(padded, self.prefix_width(start))
@@ -184,17 +192,14 @@ class DisaggRunner(ModelRunner):
             the engine thread never dispatches chunk work itself — not even
             the token upload — so its next decode dispatch is not queued
             behind any piece of the chunk."""
-            tc0 = time.perf_counter()
-            tokens = jnp.asarray(buf[None])
-            logits, chunk_kv, pool.chunk_prefix = prog.fn(
-                pool.params, tokens, pool.chunk_prefix, start, size - 1)
-            shipped = handoff.ship(chunk_kv, eager=not final)
-            if TRACER.enabled:
-                # recorded from the pool thread: this is the lane whose
-                # overlap with decode quanta the trace is meant to show
-                TRACER.complete("prefill.chunk.compute", tc0,
-                                time.perf_counter(), request_id=rid,
-                                start=start, size=size)
+            # recorded from the pool thread: this is the lane whose overlap
+            # with decode quanta the trace is meant to show
+            with TRACER.span("prefill.chunk.compute", request_id=rid,
+                             start=start, size=size):
+                tokens = jnp.asarray(buf[None])
+                logits, chunk_kv, pool.chunk_prefix = prog.fn(
+                    pool.params, tokens, pool.chunk_prefix, start, size - 1)
+                shipped = handoff.ship(chunk_kv, eager=not final)
             return logits, shipped
 
         fut = pool.submit(compute)
@@ -220,19 +225,13 @@ class DisaggRunner(ModelRunner):
             # token is sampled from
             handoff.drain(slot)
             logits = handoff.ship_aux(fut.result()[0])
-            jax.block_until_ready(logits)
+            timed_wait(logits, stats, "prefill.wait")
         t1 = time.perf_counter()
         if restarted:  # restart re-prefill is recompute overhead, not load
             stats.t_replay += t1 - t0
         else:
             stats.t_prefill += t1 - t0
         stats.prefill_chunks += 1
-        if TRACER.enabled:
-            # the ENGINE-side window (dispatch + final-chunk drain/sync),
-            # distinct from the pool thread's prefill.chunk.compute span
-            TRACER.complete("prefill.chunk.dispatch", t0, t1,
-                            request_id=req.request_id, start=start,
-                            size=size, final=final)
         return logits
 
     # ------------------------------------------------------------- release --

@@ -85,20 +85,18 @@ class KVHandoffChannel:
         passthrough, still metered).  Returns the decode-resident pytree;
         the dispatch is async, so an ``eager`` mid-prefill chunk's transfer
         overlaps the chunks still computing on the prefill pool."""
-        t0 = time.perf_counter()
-        if self._transfer is not None:
-            kv = self._transfer(kv)
-        t1 = time.perf_counter()
         nbytes = sum(x.nbytes for x in jax.tree.leaves(kv))
+        with TRACER.span("handoff.ship", lane=TRACE_LANE, bytes=nbytes, eager=eager):
+            t0 = time.perf_counter()
+            if self._transfer is not None:
+                kv = self._transfer(kv)
+            t1 = time.perf_counter()
         with self._lock:
             self.t_dispatch += t1 - t0
             self.segments += 1
             if eager:
                 self.eager_segments += 1
             self.bytes_shipped += nbytes
-        if TRACER.enabled:
-            TRACER.complete("handoff.ship", t0, t1, lane=TRACE_LANE,
-                            bytes=nbytes, eager=eager)
         return kv
 
     def ship_aux(self, tree):
