@@ -29,13 +29,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tlmm.ref import scale_epilogue
+
 
 def _decode_ternary_part(wp: jax.Array, i: int) -> jax.Array:
     """uint8 (bk/4, bn) -> int8 (bk/4, bn): the weights of code slot ``i``.
 
-    Value k = 4j + i sits in bits [2i, 2i+2) of byte j (codes 0/+1/-1 =
-    0b00/0b01/0b10).  The decode widens to int32 and maps codes by
-    arithmetic, ``(c & 1) - (c >> 1)``: v5e has no 8-bit vector compares.
+    ``repro.quant.ternary.decode_ternary_slot``'s map, widened to int32
+    first: Mosaic on v5e refuses that decode in 8 bits.
     """
     c = (wp.astype(jnp.int32) >> (2 * i)) & 0x3
     return ((c & 1) - (c >> 1)).astype(jnp.int8)
@@ -64,7 +65,7 @@ def _tlmm_kernel(x_ref, wp_ref, scale_ref, out_ref, acc_ref, *, n_k_steps: int, 
     @pl.when(k_step == n_k_steps - 1)
     def _finalize():
         # scale_ref: (bm, 1) f32 = act_scale * weight_scale (folded in ops.py)
-        out_ref[...] = (acc_ref[...].astype(jnp.float32) * scale_ref[...]).astype(out_dtype)
+        out_ref[...] = scale_epilogue(acc_ref[...], scale_ref[...], out_dtype)
 
 
 @functools.partial(

@@ -1,6 +1,6 @@
 """Jitted user-facing wrapper for the TLMM kernel.
 
-``tlmm_matmul`` is what :class:`repro.layers.linear.TernaryLinear` calls: it
+``tlmm_matmul`` is what :func:`repro.layers.linear.linear_apply` calls: it
 quantizes activations per-token to int8 (A8), folds the BitNet weight scale
 into the per-row activation scale, pads M to the sublane tile, and dispatches
 to the Pallas kernel (interpreted on the CPU, see
@@ -48,7 +48,8 @@ def tlmm_matmul(
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
 
-    x_q, act_scale = quantize_activations_int8(x2)
+    with jax.named_scope("act_quant"):
+        x_q, act_scale = quantize_activations_int8(x2)
     scale = act_scale * w.scale  # (M, 1) f32 — weight absmean folded in
 
     if not use_kernel:

@@ -2,9 +2,9 @@
 
 Two references:
 
-* ``tlmm_reference`` — unpack + int32 matmul; numerically *exact* integer
-  arithmetic, the ground truth the Pallas kernel must match bit-for-bit
-  (before the final float scale).
+* ``tlmm_reference`` — slot-major decode + int32 matmuls; numerically
+  *exact* integer arithmetic, the ground truth the Pallas kernel must match
+  bit-for-bit, and the XLA serving path of a packed linear.
 * ``tlmm_lut_reference`` — the paper's actual FPGA algorithm (C2): group
   activations in groups of 4, precompute the 3^4 = 81 add/sub combinations
   of each group, re-encode each weight group as a base-3 index, and gather.
@@ -17,19 +17,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.quant.ternary import unpack_ternary
+from repro.quant.ternary import decode_ternary_slot, unpack_ternary
 
 TL_GROUP = 4
 _POW3 = 3 ** np.arange(TL_GROUP)  # [1, 3, 9, 27]
 
 
-def tlmm_reference(x_q: jax.Array, w_packed: jax.Array, scale: jax.Array, out_dtype=jnp.bfloat16) -> jax.Array:
-    """(M,K) int8 @ unpack(w_packed) -> (M,N), scaled per-row."""
-    w = unpack_ternary(w_packed)  # (K, N) int8
-    acc = jax.lax.dot_general(
-        x_q, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
+def scale_epilogue(acc: jax.Array, scale: jax.Array, out_dtype) -> jax.Array:
+    """int32 accumulator -> output: ``acc * scale`` in float32, where
+    ``scale`` = per-row activation scale x weight absmean.  Shared by every
+    ternary matmul path (this reference, the latent path of
+    ``linear_apply``, and the Pallas kernel's finalize), so they agree bit
+    for bit on the same ternary values."""
     return (acc.astype(jnp.float32) * scale).astype(out_dtype)
+
+
+def tlmm_reference(x_q: jax.Array, w_packed: jax.Array, scale: jax.Array, out_dtype=jnp.bfloat16) -> jax.Array:
+    """(M,K) int8 @ unpack(w_packed) -> (M,N), scaled per-row.
+
+    Slot-major, as the kernel computes it: ``sum_i x[:, i::4] @ decode_i``,
+    where ``decode_i`` maps the packed bytes elementwise to the weights of
+    code slot ``i``.  The packed weight is never unpacked into one (K, N)
+    operand, so XLA can fuse each decode into its dot and stream 2 bits per
+    weight.  The int32 sums are exact: the result equals unpack-then-dot."""
+    m, k = x_q.shape
+    xs = x_q.reshape(m, k // TL_GROUP, TL_GROUP)
+    acc = None
+    for i in range(TL_GROUP):
+        with jax.named_scope("weight_quant"):
+            w_i = decode_ternary_slot(w_packed, i)  # (K//4, N) int8
+        part = jax.lax.dot_general(
+            xs[:, :, i], w_i, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        )
+        acc = part if acc is None else acc + part
+    return scale_epilogue(acc, scale, out_dtype)
 
 
 def _ternary_group_codes(w_q: np.ndarray) -> np.ndarray:

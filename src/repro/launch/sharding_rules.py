@@ -2,7 +2,9 @@
 
 Training: FSDP (big dim over the data axis) x TP (heads/ffn/vocab over the
 model axis).  Inference: TP only (fsdp=None) so decode never all-gathers
-weights.  MoE experts shard over the model axis when EP applies.
+weights.  MoE experts shard over the model axis when EP applies.  A packed
+ternary linear (``.../w/packed``, (L, K/4, N)) shards as its latent ``w``
+does: a packed row holds four consecutive input rows.
 """
 from __future__ import annotations
 
@@ -34,6 +36,8 @@ def param_pspec(path: str, leaf: Any, *, tp: Optional[str], fsdp: Optional[str],
 
     if nd == 0:
         return P()
+    if p.endswith("/w/scale"):  # a packed ternary linear's per-layer beta
+        return P(*(None,) * nd)
     if p.endswith("emb"):
         return P(tp, fsdp)
     if p.endswith("lm_head"):

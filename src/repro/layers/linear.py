@@ -7,9 +7,11 @@ Three execution regimes, all sharing one param layout:
 * ``ternary`` (infer) — weights converted once to :class:`TernaryWeight`
                         (2-bit packed) and multiplied by the TLMM op; this is
                         the "static region" engine shared by both phases.
+                        Latent weights given at inference are quantized in
+                        every call instead (the same numbers, far more bytes).
 
 The param dict is {"w": (K, N)} (+"b") for latent weights, or
-{"w": TernaryWeight} after ``convert_linear_for_inference``.
+{"w": TernaryWeight} after ``models.transformer.convert_for_inference``.
 """
 from __future__ import annotations
 
@@ -20,8 +22,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import QuantConfig
 from repro.kernels.tlmm.ops import tlmm_matmul
+from repro.kernels.tlmm.ref import scale_epilogue
 from repro.quant.act_quant import quantize_activations_int8
-from repro.quant.ternary import TernaryWeight, quantize_and_pack, ternary_quantize_ste
+from repro.quant.ternary import TernaryWeight, ternary_quantize, ternary_quantize_ste
 
 
 def linear_init(key, k: int, n: int, *, bias: bool = False, dtype=jnp.bfloat16, scale: Optional[float] = None) -> dict:
@@ -61,27 +64,15 @@ def linear_apply(
         else:
             # unconverted ternary inference: quantize on the fly (slow path)
             with jax.named_scope("act_quant"):
-                x_q, s = quantize_activations_int8(x)
-            from repro.quant.ternary import ternary_quantize
-
+                x_q, s = quantize_activations_int8(x.reshape(-1, x.shape[-1]))
             with jax.named_scope("weight_quant"):
                 w_q, beta = ternary_quantize(w.astype(jnp.float32))
             acc = jax.lax.dot_general(
-                x_q.reshape(-1, x.shape[-1]), w_q, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
+                x_q, w_q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32,
             )
-            y = (acc * s.reshape(-1, 1) * beta).reshape(*x.shape[:-1], w.shape[1]).astype(x.dtype)
+            y = scale_epilogue(acc, s * beta, x.dtype).reshape(*x.shape[:-1], w.shape[1])
     else:
         y = x @ w.astype(x.dtype)
     if "b" in params:
         y = y + params["b"].astype(y.dtype)
     return y
-
-
-def convert_linear_for_inference(params: dict, quant: QuantConfig) -> dict:
-    """Latent fp weights -> packed TernaryWeight (one-time model conversion)."""
-    if not quant.ternary or isinstance(params["w"], TernaryWeight):
-        return params
-    out = dict(params)
-    out["w"] = quantize_and_pack(params["w"].astype(jnp.float32))
-    return out

@@ -29,6 +29,7 @@ from repro.layers.mlp import mlp_apply, mlp_init
 from repro.layers.moe import moe_apply, moe_init
 from repro.layers.norm import apply_norm, norm_init
 from repro.layers.sharding import NULL_CTX, PartitionCtx
+from repro.quant.ternary import TernaryWeight, quantize_and_pack
 
 
 def _remat(fn, cfg: ModelConfig):
@@ -66,6 +67,66 @@ def init(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> dict:
             jax.random.normal(k_head, (cfg.d_model, vp), jnp.float32) * 0.02
         ).astype(dtype)
     return params
+
+
+# layer blocks whose linears ``linear_apply`` runs (the MoE experts do not)
+_LINEAR_BLOCKS = ("attn", "mlp")
+
+
+def _latent_linears(cfg: ModelConfig, params: dict) -> dict:
+    """{block: {name: (L, K, N) latent w}} of every linear weight that
+    ``linear_apply`` quantizes per call: empty unless ``cfg.quant.ternary``."""
+    if not cfg.quant.ternary:
+        return {}
+    out = {}
+    for blk in _LINEAR_BLOCKS:
+        found = {name: p["w"] for name, p in params["layers"].get(blk, {}).items()
+                 if not isinstance(p["w"], TernaryWeight)}
+        if found:
+            out[blk] = found
+    return out
+
+
+@jax.jit
+def _pack_stacked(ws: dict) -> dict:
+    # vmap over the layer axis: one absmean beta per layer, as the layer
+    # scan of the latent path takes it from each (K, N) slice
+    return jax.tree.map(jax.vmap(quantize_and_pack), ws)
+
+
+def convert_for_inference(cfg: ModelConfig, params: dict) -> dict:
+    """Ternarize and 2-bit pack every linear weight of a ternary config once
+    (one jitted call), so the phase programs read packed ``TernaryWeight``s
+    — packed (L, K/4, N), scale (L,) — instead of re-quantizing the latent
+    float weights in every call.  Embedding, norms and MoE experts stay as
+    they are.  Returns a new tree; the caller's is untouched.  For a
+    non-ternary config it returns ``params`` itself."""
+    latent = _latent_linears(cfg, params)
+    if not latent:
+        return params
+    packed = _pack_stacked(latent)
+    layers = dict(params["layers"])
+    for blk, ws in packed.items():
+        layers[blk] = dict(layers[blk])
+        for name, w in ws.items():
+            layers[blk][name] = {**layers[blk][name], "w": w}
+    return {**params, "layers": layers}
+
+
+def linear_residency(cfg: ModelConfig, params: dict) -> Tuple[int, int]:
+    """(packed, latent): how many ternary linear matrices the tree serves
+    from packed 2-bit weights, and how many it re-quantizes from latent
+    weights in every call (the MoE experts among them).  (0, 0) for a
+    non-ternary config."""
+    if not cfg.quant.ternary:
+        return 0, 0
+    layers = params["layers"]
+    packed = sum(p["w"].packed.shape[0] for blk in _LINEAR_BLOCKS
+                 for p in layers.get(blk, {}).values() if isinstance(p["w"], TernaryWeight))
+    latent = sum(w.shape[0] for ws in _latent_linears(cfg, params).values() for w in ws.values())
+    latent += sum(layers["moe"][k].shape[0] * layers["moe"][k].shape[1]
+                  for k in ("w_gate", "w_up", "w_down") if k in layers.get("moe", {}))
+    return packed, latent
 
 
 @jax.named_scope("lm_head")

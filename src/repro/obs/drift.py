@@ -35,13 +35,14 @@ PHASES = ("prefill", "decode", "spec_verify")
 
 def _n_params(runner) -> int:
     """Total parameter count of the loaded model, cached on the runner
-    (leaf ``.size`` sums only — no device transfer)."""
+    (shapes only — no device transfer; a packed ternary byte counts its
+    four weights)."""
     cached = getattr(runner, "_obs_n_params", None)
     if cached is not None:
         return cached
-    import jax
+    from repro.common.tree import tree_param_count
 
-    n = int(sum(int(x.size) for x in jax.tree.leaves(runner.params)))
+    n = tree_param_count(runner.params)
     runner._obs_n_params = n
     return n
 

@@ -70,6 +70,16 @@ _STAT_COUNTERS = (
      "step is (step - wait seconds) / steps"),
 )
 
+# (EngineStats attribute, metric name, help) — levels fixed when the
+# engine is built, hence gauges
+_STAT_GAUGES = (
+    ("packed_linears", "repro_engine_packed_linears",
+     "Ternary linear matrices served from packed 2-bit weights"),
+    ("latent_linears", "repro_engine_latent_linears",
+     "Ternary linear matrices re-quantized from latent weights in every call"),
+    ("weight_bytes", "repro_engine_weight_bytes", "Bytes of the resident weights"),
+)
+
 _LATENCY_HISTOGRAMS = (
     ("queue_wait", "repro_queue_wait_seconds",
      "Arrival to first successful admission"),
@@ -126,6 +136,8 @@ def engine_registry(core, frontend=None) -> MetricsRegistry:
         reg.counter(name, help_,
                     fn=lambda a=attr: float(getattr(core.stats, a)))
 
+    for attr, name, help_ in _STAT_GAUGES:
+        reg.gauge(name, help_, fn=lambda a=attr: float(getattr(core.stats, a)))
     reg.gauge("repro_decode_tput_tokens_per_s",
               "Decode throughput (decode_tokens / t_decode)",
               fn=lambda: core.stats.decode_tput())

@@ -24,18 +24,21 @@ import jax.numpy as jnp
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class TernaryWeight:
-    """A packed ternary weight: the on-device format of a TLMM linear."""
+    """A packed ternary weight: the on-device format of a TLMM linear.
+
+    A model's layer-stacked weight holds one per layer: packed (L, K // 4, N)
+    and scale (L,), which the layer scan slices to one matrix."""
 
     packed: jax.Array  # uint8, (K // 4, N)
     scale: jax.Array  # f32 scalar — BitNet absmean beta
 
     @property
     def k(self) -> int:
-        return self.packed.shape[0] * 4
+        return self.packed.shape[-2] * 4
 
     @property
     def n(self) -> int:
-        return self.packed.shape[1]
+        return self.packed.shape[-1]
 
 
 def ternary_quantize(w: jax.Array, eps: float = 1e-5) -> Tuple[jax.Array, jax.Array]:
@@ -76,14 +79,23 @@ def pack_ternary(w_q: jax.Array) -> jax.Array:
     return packed.astype(jnp.uint8)
 
 
+def decode_ternary_slot(packed: jax.Array, i: int) -> jax.Array:
+    """uint8 (K//4, N) -> int8 (K//4, N): the weights of code slot ``i``,
+    i.e. rows ``k = 4j + i`` of the unpacked weight.
+
+    Elementwise on the byte, so XLA can fuse it into the operand of the
+    matmul that consumes it.  The arithmetic stays in 8 bits: XLA on v5e
+    fuses this decode into the dot, but writes out an int8 matrix for one
+    that widens to int32 (the Pallas kernel's, which Mosaic needs).
+    """
+    c = (packed >> (2 * i)) & 0x3
+    return (c & 1).astype(jnp.int8) - (c >> 1).astype(jnp.int8)
+
+
 def unpack_ternary(packed: jax.Array) -> jax.Array:
-    """uint8 (K//4, N) -> int8 ternary (K, N).  Used by ref.py and the kernel."""
+    """uint8 (K//4, N) -> int8 ternary (K, N)."""
     kq, n = packed.shape
-    parts = []
-    for i in range(4):
-        bits = (packed >> (2 * i)) & 0x3
-        val = jnp.where(bits == 1, jnp.int8(1), jnp.where(bits == 2, jnp.int8(-1), jnp.int8(0)))
-        parts.append(val)
+    parts = [decode_ternary_slot(packed, i) for i in range(4)]
     # (K//4, 4, N) -> (K, N)
     return jnp.stack(parts, axis=1).reshape(kq * 4, n)
 

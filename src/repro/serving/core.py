@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.tree import tree_bytes
 from repro.configs.base import ModelConfig
 from repro.core.kv_cache import KVSlotManager, insert_prefill_kv
 from repro.core.swap import SwapAggregates, SwapController, SwapTiming
@@ -208,6 +209,13 @@ class EngineStats:
     steps: int = 0
     t_step: float = 0.0
     t_wait: float = 0.0
+    # weights as the runner holds them, fixed when it is built
+    # (ModelRunner.weight_residency): ternary linear matrices served from
+    # packed 2-bit weights, those re-quantized from latent weights in every
+    # call, and the bytes of the whole resident weight tree
+    packed_linears: int = 0
+    latent_linears: int = 0
+    weight_bytes: int = 0
 
     def decode_tput(self) -> float:
         return self.decode_tokens / self.t_decode if self.t_decode else 0.0
@@ -245,6 +253,7 @@ class EngineStats:
             "preemptions", "admission_blocks", "replayed_tokens", "t_replay",
             "draft_tokens", "accepted_tokens", "verify_rounds", "slot_rounds",
             "decode_ctx_tokens", "aborts", "sheds", "steps", "t_step", "t_wait",
+            "packed_linears", "latent_linears", "weight_bytes",
         )
         snap = {k: getattr(self, k) for k in counters}
         snap.update(
@@ -321,8 +330,22 @@ class ModelRunner:
         self.prefill_chunk = prefill_chunk
         self.spec_decode = spec_decode
         self.spec_ngram = spec_ngram
+        from repro.core.phase_engine import PhaseEngine
+        from repro.models import transformer as T
+
         self.cfg = cfg
+        self.engine = PhaseEngine(
+            cfg, mesh, max_len=max_len, cache_layout=cache_layout, kv_dtype=kv_dtype
+        )
+        # ternary linears are packed once here, never re-quantized per call
+        params = T.convert_for_inference(cfg, params)
+        if mesh is not None:  # the layout the phase programs take
+            params = jax.device_put(
+                params, self.engine.param_shardings(jax.eval_shape(lambda: params)))
         self.params = params
+        packed, latent = T.linear_residency(cfg, params)
+        self.weight_residency = dict(packed_linears=packed, latent_linears=latent,
+                                     weight_bytes=tree_bytes(params))
         self.api = get_model(cfg)
         self.mode = mode
         self.cache_layout = cache_layout
@@ -332,13 +355,6 @@ class ModelRunner:
         self.prompt_len = prompt_len
         self.block_size = block_size
         self.slots = KVSlotManager(n_slots)
-
-        from repro.core.phase_engine import PhaseEngine
-        from repro.models import transformer as T
-
-        self.engine = PhaseEngine(
-            cfg, mesh, max_len=max_len, cache_layout=cache_layout, kv_dtype=kv_dtype
-        )
         self._pa = jax.eval_shape(lambda: params)
         self._bucket_progs: Dict[int, dict] = {}  # bucket len -> phase programs
         self._chunk_progs: Dict[tuple, object] = {}  # (padded len, prefix width) -> program
@@ -1181,7 +1197,7 @@ class EngineCore:
         elif isinstance(swap_policy, str):
             swap_policy = make_policy(swap_policy)
         self.scheduler = Scheduler(self.runner, swap_policy)
-        self.stats = EngineStats()
+        self.stats = EngineStats(**self.runner.weight_residency)
         # latency-observing policies (SLOAwareSwapPolicy) read the engine's
         # own aggregates — bind() closes the control loop
         if hasattr(swap_policy, "bind"):
@@ -1294,7 +1310,7 @@ class EngineCore:
         aggregates.  Everything that holds the stats object is re-bound:
         the output processor and (when the policy observes, e.g.
         slo-aware) the swap policy, whose defer state is reset too."""
-        self.stats = EngineStats()
+        self.stats = EngineStats(**self.runner.weight_residency)
         self.out_proc = OutputProcessor(stats=self.stats)
         policy = self.scheduler.policy
         if hasattr(policy, "bind"):

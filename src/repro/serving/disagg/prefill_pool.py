@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.phase_engine import PhaseEngine, PhaseProgram
 from repro.launch.sharding_rules import params_shardings
+from repro.models.transformer import convert_for_inference
 from repro.serving.paging import cdiv
 
 
@@ -110,6 +111,8 @@ class PrefillPool:
         self._exec = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="prefill-pool",
             initializer=_deprioritize)
+        # this pool's own packed copy of the ternary linears (as ModelRunner)
+        params = convert_for_inference(cfg, params)
         if mesh is not None:
             # commit this pool's copy of the static region to its own mesh;
             # the decode pool keeps its own committed copy — weights never

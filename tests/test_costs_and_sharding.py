@@ -109,3 +109,26 @@ def test_param_pspec_rules_cover_all_archs():
         params = eval_shape_params(cfg, dtype=jnp.bfloat16)
         sh = params_shardings(params, cfg, mesh, train=True)
         assert len(jax.tree.leaves(sh)) == len(jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("name,tp_dim", [("wq", 2), ("wv", 2), ("w_up", 2),
+                                         ("wo", 1), ("w_down", 1)])
+def test_packed_linear_shards_like_its_latent_weight(name, tp_dim):
+    """A packed ternary linear keeps its latent weight's tensor-parallel dim
+    (N for an output-parallel linear, the packed K/4 rows for an
+    input-parallel one); its per-layer scale is replicated."""
+    from repro.launch.sharding_rules import param_pspec
+
+    blk = "mlp" if name.startswith("w_") else "attn"
+    leaf = jax.ShapeDtypeStruct((24, 384, 4096), jnp.uint8)
+    latent = param_pspec(f"layers/{blk}/{name}/w", jax.ShapeDtypeStruct((24, 1536, 4096),
+                                                                      jnp.float32),
+                         tp="model", fsdp=None, ep=False)
+    packed = param_pspec(f"layers/{blk}/{name}/w/packed", leaf, tp="model", fsdp=None, ep=False)
+    assert packed == latent and packed[tp_dim] == "model"
+    scale = param_pspec(f"layers/{blk}/{name}/w/scale", jax.ShapeDtypeStruct((24,), jnp.float32),
+                        tp="model", fsdp=None, ep=False)
+    assert scale == P(None)
+    # norms keep their own rule
+    assert param_pspec("layers/ln1/scale", jax.ShapeDtypeStruct((24, 1536), jnp.float32),
+                       tp="model", fsdp=None, ep=False) == P(None, None)

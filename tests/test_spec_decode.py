@@ -12,6 +12,7 @@ import pytest
 from repro.configs import reduced_config
 from repro.core.sampling import accept_length
 from repro.models import get_model
+from repro.models.transformer import convert_for_inference
 from repro.serving import EngineCore, Request, SamplingParams
 from repro.serving.core import ModelRunner
 from repro.serving.outputs import OutputProcessor
@@ -39,7 +40,9 @@ def markov(tiny):
     layers["attn"] = dict(layers["attn"], wo=jax.tree.map(jnp.zeros_like, layers["attn"]["wo"]))
     params = dict(params, layers=layers)
     tokens = jnp.arange(cfg.vocab_size, dtype=jnp.int32)[:, None]
-    logits, _ = jax.jit(lambda p, t: api.forward_prefill(p, t, cfg))(params, tokens)
+    # the weights as the engine serves them: ternary linears packed once
+    served = convert_for_inference(cfg, params)
+    logits, _ = jax.jit(lambda p, t: api.forward_prefill(p, t, cfg))(served, tokens)
     nxt = np.asarray(jnp.argmax(logits[:, : cfg.vocab_size], -1))
     best = []
     for t in range(cfg.vocab_size):

@@ -10,9 +10,10 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.tlmm.kernel import tlmm_pallas
 from repro.kernels.tlmm.ops import tlmm_matmul
-from repro.kernels.tlmm.ref import tlmm_lut_reference, tlmm_reference
+from repro.kernels.tlmm.ref import scale_epilogue, tlmm_lut_reference, tlmm_reference
 from repro.quant.act_quant import quantize_activations_int8
 from repro.quant.ternary import (
+    TernaryWeight,
     pack_ternary,
     quantize_and_pack,
     ternary_quantize,
@@ -95,3 +96,119 @@ def test_memory_footprint_is_quarter_byte():
     _, tw = _mk(8, 1024, 256)
     assert tw.packed.size == 1024 * 256 // 4
     assert tw.packed.dtype == jnp.uint8
+
+
+# --- resident packed weights: the model-level conversion and the XLA path ---
+
+def _bitnet(layers=3):
+    from repro.configs import reduced_config
+    from repro.models import get_model
+
+    cfg = reduced_config("bitnet-730m", num_layers=layers)
+    params = get_model(cfg).init(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    # layers of different magnitude: one beta for the whole stack is wrong
+    gain = jnp.asarray([0.5, 1.0, 3.0][:layers], jnp.float32)
+    for blk in ("attn", "mlp"):
+        for lin in params["layers"][blk].values():
+            lin["w"] = lin["w"] * gain[:, None, None]
+    return cfg, params
+
+
+@pytest.mark.parametrize("block,name", [("attn", "wq"), ("attn", "wo"),
+                                        ("mlp", "w_up"), ("mlp", "w_down")])
+def test_conversion_takes_one_beta_per_layer(block, name):
+    from repro.models.transformer import convert_for_inference
+
+    cfg, params = _bitnet()
+    w = params["layers"][block][name]["w"]
+    tw = convert_for_inference(cfg, params)["layers"][block][name]["w"]
+    assert tw.packed.shape == (w.shape[0], w.shape[1] // 4, w.shape[2])
+    assert tw.packed.dtype == jnp.uint8 and tw.scale.shape == (w.shape[0],)
+    betas = []
+    for l in range(w.shape[0]):
+        w_q, beta = ternary_quantize(w[l])
+        np.testing.assert_array_equal(np.asarray(tw.scale[l]), np.asarray(beta))
+        np.testing.assert_array_equal(np.asarray(tw.packed[l]), np.asarray(pack_ternary(w_q)))
+        betas.append(float(beta))
+    _, stack_beta = ternary_quantize(w)
+    assert not np.allclose(betas, float(stack_beta), rtol=0.05)
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2.5-14b"])
+def test_conversion_is_identity_without_ternary(arch):
+    from repro.configs import reduced_config
+    from repro.models import get_model
+    from repro.models.transformer import convert_for_inference, linear_residency
+
+    cfg = reduced_config(arch)
+    assert not cfg.quant.ternary
+    params = get_model(cfg).init(cfg, jax.random.PRNGKey(0))
+    assert convert_for_inference(cfg, params) is params
+    assert linear_residency(cfg, params) == (0, 0)
+
+
+def test_conversion_leaves_the_callers_tree():
+    from repro.models.transformer import convert_for_inference, linear_residency
+
+    cfg, params = _bitnet()
+    before = jax.tree.map(np.asarray, params)
+    ids = {k: id(v["w"]) for k, v in params["layers"]["attn"].items()}
+    conv = convert_for_inference(cfg, params)
+    assert all(id(params["layers"]["attn"][k]["w"]) == i for k, i in ids.items())
+    jax.tree.map(np.testing.assert_array_equal, jax.tree.map(np.asarray, params), before)
+    assert isinstance(conv["layers"]["mlp"]["w_gate"]["w"], TernaryWeight)
+    assert conv["emb"] is params["emb"] and conv["layers"]["ln1"] is params["layers"]["ln1"]
+    assert linear_residency(cfg, params) == (0, 7 * 3)
+    assert linear_residency(cfg, conv) == (7 * 3, 0)
+    assert convert_for_inference(cfg, conv)["layers"]["attn"]["wq"]["w"] is \
+        conv["layers"]["attn"]["wq"]["w"]
+
+
+@pytest.mark.parametrize("m,k,n", [(1, 64, 128), (4, 1536 // 4, 256), (13, 512, 96)])
+def test_slot_major_reference_equals_unpack_then_dot(m, k, n):
+    x, tw = _mk(m, k, n, seed=m * k)
+    x_q, s = quantize_activations_int8(x)
+    scale = s * tw.scale
+    acc = jax.lax.dot_general(x_q, unpack_ternary(tw.packed), (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.int32)
+    for out_dtype in (jnp.float32, jnp.bfloat16):
+        want = scale_epilogue(acc, scale, out_dtype)
+        got = jax.jit(tlmm_reference, static_argnames="out_dtype")(
+            x_q, tw.packed, scale, out_dtype=out_dtype)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("shape,dtype", [((4, 128), jnp.float32), ((2, 3, 256), jnp.float32),
+                                         ((8, 512), jnp.bfloat16)])
+def test_packed_and_latent_linear_agree_bit_for_bit(shape, dtype):
+    from repro.configs.base import QuantConfig
+    from repro.layers.linear import linear_apply
+
+    rng = np.random.default_rng(shape[-1])
+    x = jnp.asarray(rng.normal(size=shape), dtype)
+    w = jnp.asarray(rng.normal(size=(shape[-1], 192)) * 0.05, jnp.float32)
+    b = jnp.asarray(rng.normal(size=(192,)), jnp.float32)
+    quant = QuantConfig(mode="ternary")
+    apply = jax.jit(lambda p, x: linear_apply(p, x, quant))
+    latent = apply({"w": w, "b": b}, x)
+    packed = apply({"w": quantize_and_pack(w), "b": b}, x)
+    assert packed.dtype == latent.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(packed, np.float32), np.asarray(latent, np.float32))
+
+
+def test_moe_experts_stay_latent_and_are_counted():
+    import dataclasses
+
+    from repro.configs import reduced_config
+    from repro.configs.base import QuantConfig
+    from repro.models import get_model
+    from repro.models.transformer import convert_for_inference, linear_residency
+
+    cfg = dataclasses.replace(reduced_config("granite-moe-3b-a800m"),
+                              quant=QuantConfig(mode="ternary"))
+    params = get_model(cfg).init(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    conv = convert_for_inference(cfg, params)
+    assert conv["layers"]["moe"] is params["layers"]["moe"]
+    experts = 3 * cfg.num_layers * cfg.num_experts
+    assert linear_residency(cfg, params) == (0, 4 * cfg.num_layers + experts)
+    assert linear_residency(cfg, conv) == (4 * cfg.num_layers, experts)

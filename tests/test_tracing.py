@@ -24,7 +24,7 @@ SCOPES = ("embed", "norm", "attention", "kv_write", "linear", "weight_quant",
 def tiny():
     cfg = reduced_config("bitnet-730m", num_layers=2, d_model=64, vocab_size=256,
                          num_heads=4, num_kv_heads=2)
-    assert cfg.quant.ternary  # the latent weights quantize in every call
+    assert cfg.quant.ternary  # packed linears: act_quant and the 2-bit decode
     params = get_model(cfg).init(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     return cfg, params
 
