@@ -9,7 +9,7 @@ served tokens' widest logit gap against the float32 reference (the number
 a run compares) and, with ``--control``, the same reading for the tokens
 a lower-precision reference ranks first at the same positions, with the
 run's ``correct`` decided on it: the configuration's ``"control"``, or the
-precision of ``bench.reference.model`` named.
+precision named, one of its family's ``CONTROLS``.
 The benchmark's own runs never run this.
 """
 import time
@@ -41,6 +41,9 @@ def main(argv=None) -> int:
     control = args.control
     if control == "":
         control = cell.config["control"]
+    controls = spec.family(cell.config).CONTROLS
+    if control is not None and control not in controls:
+        ap.error(f"control {control!r} is not one of {controls}")  # before a window is spent
     t_start = T_START
     for seed in [int(s) for s in args.seeds.split(",")]:
         r = cell_run.execute(cell, seed, args.seconds, False, t_start=t_start,

@@ -12,7 +12,7 @@ import time
 import types
 from pathlib import Path
 
-from bench.harness import check, session, spec, traffic, trace as trace_mod
+from bench.harness import check, layers, session, spec, traffic
 from bench.harness import window as win
 from bench.harness.peaks import peak_for
 
@@ -102,7 +102,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             t_start: float, require_tpu: bool = True, patch=None, control=None) -> dict:
     """Run the cell once; returns the result line's object.  ``patch(eng)``
     may alter the engine before warm-up (the fault tests break it so).
-    With ``control`` (a precision of ``bench.reference.model``), that
+    With ``control`` (one of the family's ``CONTROLS``), that
     lower-precision reference takes the program's place in the comparison:
     ``correct`` is decided on the tokens it ranks first at the served
     positions, and the program's own reading is kept under ``readings``."""
@@ -128,7 +128,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     eng = session.build_engine(cfg, params, cell.engine)
     if patch is not None:
         patch(eng)
-    sess = session.Session(eng, seed, c["vocab_size"])
+    sess = session.Session(eng, seed, cfg.vocab_size)
     n_warm = sess.warm_up(mix)
     t_warm = time.perf_counter() - t
     n_setup_compiles = compiles.n
@@ -175,7 +175,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     reduced = None
     if trace:
         files = sorted(TRACE_DIR.glob("**/*.xplane.pb"))
-        reduced = trace_mod.reduce_events(trace_mod.events_from_xplane(str(files[-1])))
+        reduced = layers.reduce_events(layers.events_from_xplane(str(files[-1])))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
     ctx = types.SimpleNamespace(
@@ -217,7 +217,10 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     }
     if reduced is not None:
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
-        result["breakdown"] = {k: reduced[k] for k in ("device_ops", "idle_gaps")}
+        # idle by innermost span as [name, seconds] pairs, at most 10, as the other lists
+        by_span = [[n, t] for n, t in reduced["idle_by_span"].items()][:10]
+        result["breakdown"] = {"device_ops": reduced["device_ops"],
+                               "idle_gaps": reduced["idle_gaps"], "idle_by_span": by_span}
     result["readings"] = verdicts
     name = "control_gap" if control else "logit_gap"
     result["check"] = {name: {"value": gap, "limit": limit}}

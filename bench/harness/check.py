@@ -2,8 +2,9 @@
 
 After the window, a sample of the requests the run finished, drawn from the
 seed and always holding the one with the most served tokens, is run once
-through the plain reference (``bench/reference``) over its prompt and the
-tokens the engine served.  At each served position the number compared is
+through the plain reference of the configuration's family
+(``bench.harness.spec.family``) over its prompt and the tokens the engine
+served.  At each served position the number compared is
 how far the served token's reference logit lies below the reference's best
 there; a run is correct when the widest such gap stays within the cell's
 limit.  The tokens are greedy, so a sound engine serves the reference's
@@ -19,7 +20,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.reference import model as ref
+from bench.harness.spec import family
 
 
 def sample(streams: list, requests: dict, k: int, rng: np.random.Generator) -> list:
@@ -35,13 +36,14 @@ def sample(streams: list, requests: dict, k: int, rng: np.random.Generator) -> l
     return [pool[0].rid] + [s.rid for s in rest]
 
 
-def _positions(req):
+def _positions(req, q_block: int):
     """The sequence the reference reads, the positions whose logits chose
-    the served tokens, and those tokens padded as the reference pads rows."""
+    the served tokens, and those tokens padded as the reference pads rows
+    (to a multiple of ``q_block``)."""
     p, out = np.asarray(req.prompt, np.int32), np.asarray(req.out_tokens, np.int32)
     seq = np.concatenate([p, out[:-1]])
     pos = np.arange(len(p) - 1, len(p) - 1 + len(out))
-    return seq, pos, np.pad(out, (0, -len(out) % ref.Q_BLOCK), mode="edge")
+    return seq, pos, np.pad(out, (0, -len(out) % q_block), mode="edge")
 
 
 def _stats(g: np.ndarray) -> dict:
@@ -57,9 +59,10 @@ def verdict(weights, config: dict, reqs: list, control: str = None) -> dict:
     ranks first at the same positions (``"control"``)."""
     import jax.numpy as jnp
 
+    ref = family(config)
     served, low = [], []
     for req in reqs:
-        seq, pos, out = _positions(req)
+        seq, pos, out = _positions(req, ref.Q_BLOCK)
         want = ref.logits(weights, config, seq, pos)
         served.append(np.asarray(ref.gaps(want, out))[: len(pos)])
         if control is not None:

@@ -2,8 +2,11 @@
 beside ``bench.harness.trace``'s reduction, and the per-layer numbers taken
 from them.
 
-``events_from_xplane`` returns the lists of ``trace.events_from_xplane``
-with two additions: the engine's spans (``repro.obs.trace``: ``engine.*``,
+``events_from_xplane`` reads the profiler's ``.xplane.pb`` into the plain
+event lists ``trace.reduce_events`` takes (``{"ops": {chip: [...]},
+"modules": {chip: [...]}, "host": [...]}``, every TPU's "XLA Ops" and "XLA
+Modules" lines and the host spans as ``(name, start_ns, end_ns)``): the
+harness's spans and the engine's (``repro.obs.trace``: ``engine.*``,
 ``prefill*``, ``decode.*``, ``swap``, ``replay*``, ``handoff.*``) among the
 host spans, and each device operation as ``(name, start_ns, end_ns,
 op_name)``.  ``op_name`` is the XLA metadata of the operation's HLO
@@ -50,8 +53,6 @@ SCOPES = ("embed", "norm", "attention", "kv_write", "linear", "weight_quant",
           "act_quant", "mlp", "lm_head")
 # first word of the engine's span names (repro.obs.trace users)
 ENGINE_SPANS = ("engine", "prefill", "decode", "swap", "replay", "handoff")
-# EngineStats counters a window reads as deltas for step_host_ms
-COUNTERS = ("steps", "t_step", "t_wait")
 DECODE = "jit_decode_"  # the decode program's module, any layout and shape
 _RUN_ID = re.compile(r"\(\d+\)$")
 
@@ -150,8 +151,8 @@ def hlo_op_names(raw: bytes) -> dict:
 
 
 def events_from_xplane(path: str) -> dict:
-    """``trace.events_from_xplane``'s lists with the engine's spans among
-    the host spans and each device operation's ``op_name``."""
+    """The harness's and the engine's host spans, and every TPU's modules
+    and operations, each operation with its ``op_name``."""
     from jax.profiler import ProfileData
 
     with open(path, "rb") as f:

@@ -9,6 +9,7 @@ which is when a client would have it.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import os
 import resource
@@ -18,13 +19,9 @@ from typing import Dict, List, Optional
 import jax
 from bench.harness import traffic as tr
 from bench.harness.window import Stream
+from repro.obs.trace import TRACER
 
 ANN = jax.profiler.TraceAnnotation
-
-# the EngineStats counters a window reads as deltas
-COUNTERS = ("prefill_tokens", "decode_tokens", "decode_rounds", "prefill_chunks",
-            "t_prefill", "t_decode", "slot_rounds", "decode_ctx_tokens", "swaps",
-            "preemptions", "admission_blocks", "prefix_hits", "prefix_misses")
 
 
 def build_engine(cfg, params, engine: dict):
@@ -35,7 +32,12 @@ def build_engine(cfg, params, engine: dict):
 
 
 def counters(eng) -> dict:
-    return {k: getattr(eng.stats, k) for k in COUNTERS}
+    """Every counter of the engine's ``EngineStats`` (its int and float
+    fields), which a window reads as deltas: a counter the program adds
+    reaches the metrics and the family's work with no change here."""
+    st = eng.stats
+    return {f.name: getattr(st, f.name) for f in dataclasses.fields(st)
+            if isinstance(getattr(st, f.name), (int, float))}
 
 
 class CompileCount:
@@ -308,7 +310,8 @@ def run_open(sess: Session, items: List[tr.Item], pre_roll_s: float, seconds: fl
 
 class TraceSlice:
     """The profiler over a slice of the traffic, marked by the host
-    annotation ``bench.trace_window``."""
+    annotation ``bench.trace_window``, with the engine's spans
+    (``repro.obs.trace.TRACER``) recorded inside the slice only."""
 
     def __init__(self, log_dir: str):
         self.dir = log_dir
@@ -318,9 +321,12 @@ class TraceSlice:
         jax.profiler.start_trace(self.dir)
         self._ann = ANN("bench.trace_window")
         self._ann.__enter__()
+        TRACER.enable()
 
     def stop(self) -> None:
         if self._ann is not None:
+            TRACER.disable()
+            TRACER.clear()
             self._ann.__exit__(None, None, None)
             self._ann = None
             jax.profiler.stop_trace()

@@ -2,10 +2,36 @@
 cell's own file under ``bench/cells/`` names its configuration
 (``bench/configs/``), its traffic mix (``bench/traffic/``) and the engine
 settings a user would pass.  Adding a cell adds files; nothing here changes.
+
+Whatever depends on the model's equations comes from the configuration's
+family module: the file its ``"reference"`` key names, under ``bench/``
+(:func:`family`).  A family module supplies
+
+* ``model_fields(c) -> dict``: the ``ModelConfig`` fields the program takes,
+  put over the program's own entry for ``c["arch"]``;
+* ``layout(c)``: ``(path, shape, init)`` for every weight leaf, in the order
+  the leaves are drawn from the seed.  ``path`` is a tuple of keys into the
+  tree the engine takes, a leaf of any rank, in ``layers`` or outside it.
+  ``init`` is ``("normal", std)``, ``("gain",)``, ``("bias",)``, or
+  ``("embed", std)`` / ``("head", std)`` for the vocabulary's rows of the
+  embedding (axis 0) and columns of the head (axis 1), which
+  ``weights.make_weights`` pads;
+* the plain float32 reference: ``logits(weights, c, tokens, positions,
+  precision="reference")``, ``gaps(ref_logits, tokens)``, ``Q_BLOCK`` (rows
+  ``logits`` pads its positions to) and ``CONTROLS`` (the lower precisions
+  ``logits`` takes);
+* the roofline work, in ``costs.Work``: ``weight_bytes(c)``,
+  ``kv_bytes_per_token(c, kv_dtype)``, ``decode(c, stats, kv_dtype)``, the
+  work of the window's decode rounds from its counter deltas (``stats``:
+  every counter of the engine's ``EngineStats``), and ``prefill(c,
+  prompt_len)``, one prompt's prefill.
+
+Like the reference, a family module imports nothing of the program.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 from pathlib import Path
 
@@ -60,33 +86,30 @@ def load_cell(name: str) -> Cell:
     )
 
 
-# bench config key -> repro ModelConfig field
-_MODEL_FIELDS = {
-    "hidden_size": "d_model",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "tie_word_embeddings": "tie_embeddings",
-    "attention_bias": "qkv_bias",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-}
+def family(config: dict):
+    """The family module that ``config["reference"]`` names, a ``.py`` file
+    under ``bench/``, imported once per path (as ``bench.<dir>.<file>``)."""
+    name = config.get("name", "?")
+    if "reference" not in config:
+        raise KeyError(f"configuration {name!r} has no 'reference' key naming its family module")
+    rel = config["reference"]
+    path = (ROOT / rel).resolve()
+    if not path.is_relative_to(BENCH) or path.suffix != ".py":
+        raise ValueError(f"configuration {name!r}: reference {rel!r} is not a .py file under "
+                         f"{BENCH}")
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {name!r}: reference {rel!r} is missing")
+    return importlib.import_module(".".join(path.relative_to(ROOT).with_suffix("").parts))
 
 
 def model_config(config: dict):
     """The program's ``ModelConfig`` for a bench configuration: the
-    program's own entry for ``arch``, with every size the bench file states
-    put in its place, so the program runs exactly what the file says."""
+    program's own entry for ``arch``, with the fields the configuration's
+    family gives put in their place, so the program runs exactly what the
+    file says."""
     from repro.configs import get_config
     from repro.configs.base import QuantConfig
 
-    if config.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"{config['name']}: only SwiGLU blocks are served")
-    base = get_config(config["arch"])
-    fields = {f: config[k] for k, f in _MODEL_FIELDS.items() if k in config}
     mode = "ternary" if config["weights"] == "ternary" else "bf16"
-    return dataclasses.replace(base, quant=QuantConfig(mode=mode), norm="rmsnorm",
-                               act="silu", moe=False, sliding_window=None, **fields)
+    return dataclasses.replace(get_config(config["arch"]), quant=QuantConfig(mode=mode),
+                               **family(config).model_fields(config))

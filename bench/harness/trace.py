@@ -10,9 +10,10 @@ the harness annotation (``bench.step``, ``bench.submit``,
 that encloses it, with its HLO text cut to the result and operand types;
 an operation that encloses others (a loop) counts only through them.
 
-``events_from_xplane`` reads the profiler's ``.xplane.pb``;
-``reduce_events`` works on the plain event lists it returns, which is also
-the form of the recorded trace the tests keep.
+``reduce_events`` works on plain event lists, which
+``bench.harness.layers.events_from_xplane`` reads from the profiler's
+``.xplane.pb``; they are also the form of the recorded traces the tests
+keep.
 """
 from __future__ import annotations
 
@@ -35,28 +36,6 @@ def op_name(text: str, width: int = 160) -> str:
         return text[:width]
     rest = _OPERAND.sub("", _LAYOUT.sub("", rest.split(", kind=")[0].split(", condition=")[0]))
     return f"{name} = {rest.strip()}"[:width]
-
-
-def events_from_xplane(path: str) -> dict:
-    """{"ops": {chip: [(name, start_ns, end_ns)]}, "modules": {chip: [...]},
-    "host": [(name, start_ns, end_ns)]} for the harness's spans and every
-    TPU's "XLA Ops" and "XLA Modules" lines."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    out = {"ops": {}, "modules": {}, "host": []}
-    for plane in data.planes:
-        m = _DEVICE.match(plane.name)
-        for line in plane.lines:
-            if m and line.name in ("XLA Ops", "XLA Modules"):
-                key = "ops" if line.name == "XLA Ops" else "modules"
-                out[key].setdefault(m.group(1), []).extend(
-                    (e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events)
-            elif plane.name.startswith("/host"):
-                out["host"].extend(
-                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                    for e in line.events if e.name == WINDOW or e.name in HOST_SPANS)
-    return out
 
 
 def _union(intervals, lo, hi):

@@ -1,4 +1,5 @@
-"""Plain forward pass of the benchmark's decoder-only models, in float32.
+"""Plain forward pass of the dense family's decoder-only block, in float32
+(``bench.reference.dense`` is the family module that re-exports it).
 
 It imports nothing of the program.  It follows the published equations:
 

@@ -36,11 +36,12 @@ def main(argv=None) -> int:
     dev = cell_run.devices_for(cell.chips, True)[0]
     cell_run.use_compile_cache()
     c, mix = cell.config, cell.traffic
+    cfg = spec.model_config(c)
     params = make_weights(c, args.seed, dev)
-    eng = session.build_engine(spec.model_config(c), params, cell.engine)
-    session.Session(eng, args.seed, c["vocab_size"]).warm_up(mix)
+    eng = session.build_engine(cfg, params, cell.engine)
+    session.Session(eng, args.seed, cfg.vocab_size).warm_up(mix)
     for rate in [float(r) for r in args.rates.split(",")]:
-        sess = session.Session(eng, args.seed, c["vocab_size"])
+        sess = session.Session(eng, args.seed, cfg.vocab_size)
         items = traffic.open_loop(mix, args.seconds, rate=rate)
         t = time.perf_counter()
         t0, t1 = session.run_open(sess, items, float(mix["pre_roll_s"]), args.seconds,

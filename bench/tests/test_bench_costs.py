@@ -1,14 +1,19 @@
-"""The cost and peak tables: counts pinned to hand arithmetic."""
+"""The cost and peak tables: counts pinned to hand arithmetic (the dense
+family's work, ``bench.reference.dense``, read through the loader)."""
 import json
 
 import pytest
 
-from bench.harness import costs, peaks
+from bench.harness import peaks, spec
 from bench.tests.tiny import BENCH
 
 
 def cfg(name):
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def costs(c):
+    return spec.family(c)
 
 
 @pytest.mark.parametrize("name,params,kv", [
@@ -19,24 +24,25 @@ def cfg(name):
 ])
 def test_params_and_kv_bytes(name, params, kv):
     c = cfg(name)
-    assert costs.param_count(c) == params
-    assert costs.kv_bytes_per_token(c) == kv
+    assert costs(c).param_count(c) == params
+    assert costs(c).kv_bytes_per_token(c) == kv
 
 
 def test_weight_bytes_at_stated_storage():
     # ternary linears at 2 bits, the tied embedding and norms at 2 bytes
     b = cfg("bitnet-730m")
-    assert costs.weight_bytes(b) == 24 * 28_311_552 / 4 + (32002 * 1536 + 24 * 3072 + 1536) * 2
+    assert costs(b).weight_bytes(b) == 24 * 28_311_552 / 4 + (32002 * 1536 + 24 * 3072 + 1536) * 2
     q = cfg("qwen2.5-14b")
-    assert costs.weight_bytes(q) == 2 * 4_860_363_776  # 9.72 GB of bfloat16
+    assert costs(q).weight_bytes(q) == 2 * 4_860_363_776  # 9.72 GB of bfloat16
 
 
 def test_decode_work():
     b = cfg("bitnet-730m")
-    w = costs.decode(b, rounds=10, slot_rounds=40, ctx_tokens=40 * 7000)
+    w = costs(b).decode(b, {"decode_rounds": 10, "slot_rounds": 40,
+                            "decode_ctx_tokens": 40 * 7000})
     assert w.int8_ops == 2.0 * 24 * 28_311_552 * 40
     assert w.bf16_flops == 2.0 * 1536 * 32002 * 40 + 4.0 * 24 * 24 * 64 * (40 * 7000 + 40)
-    assert w.bytes == 10 * costs.weight_bytes(b) + (40 * 7000 + 40) * 147_456
+    assert w.bytes == 10 * costs(b).weight_bytes(b) + (40 * 7000 + 40) * 147_456
     # memory bound: 4 streams at 7000 tokens read ~4.1 GB of KV a round
     peak = peaks.peak_for("TPU v5 lite")
     assert w.seconds(peak) == pytest.approx(w.bytes / 819e9)
@@ -46,7 +52,7 @@ def test_decode_work():
 def test_prefill_work():
     q = cfg("qwen2.5-14b")
     n = 1024
-    w = costs.prefill(q, n)
+    w = costs(q).prefill(q, n)
     lin = 2.0 * 12 * 275_251_200 * n
     head = 2.0 * 5120 * 152064
     attn = 4.0 * 12 * 40 * 128 * n * (n + 1) / 2

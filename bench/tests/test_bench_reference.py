@@ -8,7 +8,6 @@ import pytest
 
 from bench.harness import session, spec
 from bench.harness.weights import make_weights
-from bench.reference import model as ref
 from bench.tests import tiny
 
 # |engine - reference| over the largest |reference| logit.  The ternary
@@ -51,7 +50,7 @@ def test_engine_matches_reference(name, engine):
     c, params, out, got = served_logits(name, engine, prompt, max_new=8)
     seq = np.concatenate([prompt, np.asarray(out[:-1], np.int32)])
     pos = np.arange(len(prompt) - 1, len(seq))
-    want = np.asarray(ref.logits(params, c, seq, pos))[: len(pos)]
+    want = np.asarray(spec.family(c).logits(params, c, seq, pos))[: len(pos)]
     assert got.shape == want.shape
     err = np.abs(got - want).max() / np.abs(want).max()
     assert err < TOL[name], err

@@ -1,20 +1,17 @@
-"""Traced runs of one cell that also read the engine's spans and the model's
-layer scopes, on the chip.
+"""Traced runs of one cell that keep more of the trace's reading, on the
+chip.
 
     python3 bench/trace_layers.py --workload <cell> --runs SEED:SPANS[,...] \\
         --seconds <s> [--record PATH] [--out PATH]
 
-Each run is ``bench/run.py --trace 1`` (``cell_run.execute``) with three
-additions, made inside this process only: the engine's host counters
-(``steps``, ``t_step``, ``t_wait``) are read as window deltas; the traced
-slice is reduced by ``bench.harness.layers``; and ``repro.obs.trace.TRACER``
-records with SPANS 1 during the traced slice only, with 2 from set-up to
-the end (the window too, which prices the spans), with 0 never.  Its JSON
-line adds ``step_host_ms``, ``decode_attention_ms`` and ``weight_quant_ms``
-to the metrics and ``idle_by_span``, ``scopes``, ``runs``, ``spans`` and
-``clock_shift_ms`` to the breakdown.  ``--record`` writes the first 0.3 s of
-the first run's slice as gzipped events, the form ``bench/tests/data``
-keeps.
+Each run is ``bench/run.py --trace 1`` (``cell_run.execute``), whose traced
+slice already holds the engine's spans and is read by
+``bench.harness.layers``.  SPANS 1 is that run as it stands; SPANS 2 also
+records ``repro.obs.trace.TRACER`` from set-up on (the window too, which
+prices the spans).  The JSON line adds ``weight_quant_ms`` to the metrics
+and ``scopes``, ``runs``, ``spans`` and ``clock_shift_ms`` to the
+breakdown.  ``--record`` writes the first 0.3 s of the first run's slice as
+gzipped events, the form ``bench/tests/data`` keeps.
 """
 import time
 
@@ -30,9 +27,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 RECORD_S = 0.3
-METRICS = ({"name": "step_host_ms", "unit": "ms"},
-           {"name": "decode_attention_ms", "unit": "ms"},
-           {"name": "weight_quant_ms", "unit": "ms"})
+METRICS = ({"name": "weight_quant_ms", "unit": "ms"},)
 
 
 def clip(ev: dict, seconds: float) -> dict:
@@ -51,7 +46,6 @@ def clip(ev: dict, seconds: float) -> dict:
             "host": host}
 
 
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -60,24 +54,16 @@ def main(argv=None) -> int:
     ap.add_argument("--record", default=None, help="write the first run's slice here")
     ap.add_argument("--out", default=None, help="append the JSON lines here too")
     args = ap.parse_args(argv)
+    runs = [tuple(int(x) for x in item.split(":")) for item in args.runs.split(",")]
+    if any(spans not in (1, 2) for _, spans in runs):
+        ap.error("SPANS is 1 (the engine's spans in the traced slice) or 2 (from set-up on)")
 
     import dataclasses
 
-    from bench.harness import cell_run, layers, session, spec
+    from bench.harness import cell_run, layers, spec
     from repro.obs.trace import TRACER
 
-    state = {"spans": 0, "reduced": None, "record": args.record}
-
-    class Slice(session.TraceSlice):
-        def start(self):
-            super().start()
-            if state["spans"] == 1:
-                TRACER.enable()
-
-        def stop(self):
-            if state["spans"] == 1:
-                TRACER.disable()
-            super().stop()
+    state = {"reduced": None, "record": args.record}
 
     class Reader:
         events_from_xplane = staticmethod(layers.events_from_xplane)
@@ -91,16 +77,11 @@ def main(argv=None) -> int:
             state["reduced"] = layers.reduce_events(ev)
             return state["reduced"]
 
-    session.COUNTERS = tuple(session.COUNTERS) + layers.COUNTERS
-    session.TraceSlice = Slice
-    cell_run.trace_mod = Reader
+    cell_run.layers = Reader
 
     cell = spec.load_cell(args.workload)
     cell = dataclasses.replace(cell, per_layer=cell.per_layer + METRICS)
-    for i, item in enumerate(args.runs.split(",")):
-        seed, spans = (int(x) for x in item.split(":"))
-        state["spans"] = spans
-
+    for i, (seed, spans) in enumerate(runs):
         def patch(eng, spans=spans):
             if spans == 2:
                 TRACER.enable()
@@ -113,7 +94,7 @@ def main(argv=None) -> int:
             TRACER.disable()
             TRACER.clear()
         r = state["reduced"]
-        keys = ("idle_by_span", "scopes", "runs", "spans", "clock_shift_ms")
+        keys = ("scopes", "runs", "spans", "clock_shift_ms")
         result["breakdown"].update({k: r[k] for k in keys})
         result.update(workload=args.workload, seed=seed, spans=spans)
         line = json.dumps(result)
