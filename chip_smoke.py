@@ -7,13 +7,16 @@ One chip:
 
 1. ``repro.launch.serve.main`` serves 8 requests (512-token prompts, 32 new
    tokens, 4 slots, max_len 1024) in pdswap mode, once per cache layout
-   (contiguous; paged with 16-token pages), on the default XLA attention.
-2. The same engines with ``use_pallas=True`` for {contiguous, paged} x
-   {fp, int8} KV, beside the XLA engines of the same layout and KV dtype:
-   every request must finish, every prefill and decode logit must be
-   finite, each kernel-path program must hold compiled Pallas kernels
-   (``tpu_custom_call``), and the kernel path's prefill last-token logits
-   must match the XLA path's within ``PREFILL_LOGIT_RTOL``.  Greedy token
+   (contiguous; paged with 16-token pages), on the default XLA attention;
+   the packed ternary linears run the compiled TLMM kernel in every engine
+   on the chip (``tlmm_matmul`` decides from the backend, not a knob).
+2. The same engines with ``use_pallas=True`` (the attention kernels) for
+   {contiguous, paged} x {fp, int8} KV, beside the XLA engines of the same
+   layout and KV dtype: every request must finish, every prefill and
+   decode logit must be finite, each kernel-path program must hold compiled
+   Pallas kernels (``tpu_custom_call``), and the kernel path's prefill
+   last-token logits must match the XLA path's within
+   ``PREFILL_LOGIT_RTOL``.  Greedy token
    agreement between the two paths is reported, not gated: the paths
    round differently, and a random-weight model's near-ties can flip.
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import kernels
 from repro.configs.base import ModelConfig
 from repro.layers.sharding import (
     DECODE_RULES,
@@ -111,6 +112,8 @@ class PhaseEngine:
             "KV-cache subsystem) are mutually exclusive")
         self.cfg = cfg
         self.mesh = mesh
+        # GSPMD partitions the programs over several devices
+        self.spmd = mesh is not None and mesh.size > 1
         self.api = get_model(cfg)
         self.max_len = max_len
         self.kv_quant = kv_quant
@@ -144,10 +147,13 @@ class PhaseEngine:
         donation, so the registry the analysis pass audits reflects what the
         compiler was actually told.  The jitted function is named
         ``program_name(key, phase)``, so the compiled module (and a profiler
-        trace) reads ``jit_decode_4x9216``, not ``jit_fn``."""
+        trace) reads ``jit_decode_4x9216``, not ``jit_fn``.  A program
+        over a mesh of several devices is traced ``kernels.partitioned``:
+        GSPMD cannot partition a Mosaic kernel."""
         @functools.wraps(fn)
         def named(*args):
-            return fn(*args)
+            with kernels.partitioned(self.spmd):
+                return fn(*args)
 
         named.__name__ = named.__qualname__ = program_name(key, phase)
         prog = PhaseProgram(

@@ -7,18 +7,19 @@ matmul into index->lookup->accumulate, eliminating DDR weight streaming.
 TPU adaptation (DESIGN.md §2): the *memory-system* property is what matters —
 1.58-bit weights resident in fast memory so the linear layers stop being
 weight-bandwidth-bound.  Here the packed 2-bit weights (uint8, 4 weights/byte,
-see repro.quant.ternary) are streamed HBM->VMEM at 0.25 B/weight, decoded to
-int8 *inside* the kernel, and multiplied on the MXU (int8 x int8 -> int32),
-which is the roofline-correct compute engine on TPU — a LUT-gather
-realization would run on the VPU at ~1/50th the throughput.  The faithful
-LUT algorithm is kept as an oracle in ref.py (tlmm_lut_reference) and the
-property tests assert all three agree exactly in integer arithmetic.
+see repro.quant.ternary) are streamed HBM->VMEM at 0.25 B/weight, once per
+call, decoded to int8 *inside* the kernel, and multiplied on the MXU (int8 x
+int8 -> int32), which is the roofline-correct compute engine on TPU — a
+LUT-gather realization would run on the VPU at ~1/50th the throughput.  The
+faithful LUT algorithm is kept as an oracle in ref.py (tlmm_lut_reference)
+and the property tests assert all three agree exactly in integer arithmetic.
 
 VMEM tiling: grid (M/bm, N/bn, K/bk); per step the kernel holds
   x tile   (bm, bk)   int8
   w tile   (bk/4, bn) uint8   <- 4x smaller than an int8 weight tile
   acc      (bm, bn)   int32 scratch (persistent across the K dimension)
 K is the innermost, sequential ("arbitrary") grid dim; M/N are parallel.
+``repro.kernels.tlmm.ops.tlmm_tiles`` picks the blocks from the shape.
 """
 from __future__ import annotations
 
@@ -31,15 +32,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tlmm.ref import scale_epilogue
 
+_BYTE_LSB = 0x01010101  # bit 0 of each byte of a 32-bit word
+_EVEN_BITS = 0x55555555  # the low bit of every 2-bit code
 
-def _decode_ternary_part(wp: jax.Array, i: int) -> jax.Array:
-    """uint8 (bk/4, bn) -> int8 (bk/4, bn): the weights of code slot ``i``.
 
-    ``repro.quant.ternary.decode_ternary_slot``'s map, widened to int32
-    first: Mosaic on v5e refuses that decode in 8 bits.
-    """
-    c = (wp.astype(jnp.int32) >> (2 * i)) & 0x3
-    return ((c & 1) - (c >> 1)).astype(jnp.int8)
+def _decode_ternary_slots(wp: jax.Array) -> list:
+    """uint8 (bk/4, bn) -> four int8 (bk/4, bn): the weights of each code
+    slot, ``repro.quant.ternary.decode_ternary_slot``'s map.
+
+    Decoded four bytes at a time: the tile is viewed as 32-bit words and
+    every operation keeps to its byte (masks, shifts of at most 7 bits whose
+    spill the masks drop, a product of 0/1 bytes by 0xFE that cannot carry),
+    so which byte of a word holds which row never matters and the int8
+    result is the word viewed back.  Mosaic on v5e has no 8-bit vector
+    arithmetic; this needs no widening of each byte to 32 bits either."""
+    w = pltpu.bitcast(wp, jnp.int32)
+    lo = w & _EVEN_BITS  # code 0b01: +1
+    hi = jax.lax.shift_right_logical(w, 1) & _EVEN_BITS  # code 0b10: -1
+    nonzero = lo ^ hi  # 0b11 is unused and decodes to 0
+    negative = nonzero & hi
+    slots = []
+    for i in range(4):
+        nz = jax.lax.shift_right_logical(nonzero, 2 * i) & _BYTE_LSB
+        neg = jax.lax.shift_right_logical(negative, 2 * i) & _BYTE_LSB
+        slots.append(pltpu.bitcast(nz | neg * 0xFE, jnp.int8))  # 1 -> 0x01, -1 -> 0xFF
+    return slots
 
 
 def _tlmm_kernel(x_ref, wp_ref, scale_ref, out_ref, acc_ref, *, n_k_steps: int, out_dtype):
@@ -52,12 +69,11 @@ def _tlmm_kernel(x_ref, wp_ref, scale_ref, out_ref, acc_ref, *, n_k_steps: int, 
     # x arrives slot-major within each K block (see tlmm_pallas), so code
     # slot i multiplies the contiguous lane slice [i*q, (i+1)*q) of x: four
     # int8 MXU dots, and no sublane interleave of the decoded weights.
-    wp = wp_ref[...]  # (bk/4, bn) uint8
-    q = wp.shape[0]
-    for i in range(4):
+    q = wp_ref.shape[0]
+    for i, w_i in enumerate(_decode_ternary_slots(wp_ref[...])):
         acc_ref[...] += jax.lax.dot_general(
             x_ref[:, i * q:(i + 1) * q],
-            _decode_ternary_part(wp, i),
+            w_i,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
@@ -87,7 +103,7 @@ def tlmm_pallas(
     kq, n = w_packed.shape
     assert kq * 4 == k, (k, kq)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
-    assert bk % 4 == 0
+    assert bk % 16 == 0, bk  # whole 32-bit words of packed rows
     n_k_steps = k // bk
 
     # Slot-major order within each K block: column 4j + i of a block moves
@@ -108,6 +124,9 @@ def tlmm_pallas(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # a layer scan's slice of the (L, K/4, N) stack is read in
+            # place, not copied out for the call
+            allow_input_fusion=(False, True, False),
         ),
         interpret=interpret,
     )(x_q, w_packed, scale)

@@ -64,7 +64,7 @@ def attention_init(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> dict:
 def _project_qkv(params, x, cfg: ModelConfig, positions, *, training, rope=True):
     b, s, _ = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    kw = dict(quant=cfg.quant, training=training, use_pallas=cfg.use_pallas)
+    kw = dict(quant=cfg.quant, training=training)
     q = linear_apply(params["wq"], x, **kw).reshape(b, s, h, hd)
     k = linear_apply(params["wk"], x, **kw).reshape(b, s, hkv, hd)
     v = linear_apply(params["wv"], x, **kw).reshape(b, s, hkv, hd)
@@ -183,7 +183,7 @@ def attention_prefill(
 
     out = pctx.shard(out, "batch", "heads", "seq", "head_dim")
     y = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.num_heads * cfg.head_dim)
-    y = linear_apply(params["wo"], y, quant=cfg.quant, training=training, use_pallas=cfg.use_pallas)
+    y = linear_apply(params["wo"], y, quant=cfg.quant, training=training)
     return y, (kt, vt)
 
 
@@ -258,7 +258,7 @@ def attention_prefill_chunk(
 
     out = pctx.shard(out, "batch", "heads", "seq", "head_dim")
     y = out.transpose(0, 2, 1, 3).reshape(b, c, h * hd)
-    y = linear_apply(params["wo"], y, quant=cfg.quant, training=False, use_pallas=cfg.use_pallas)
+    y = linear_apply(params["wo"], y, quant=cfg.quant, training=False)
     return y, (kt, vt)
 
 
@@ -365,7 +365,7 @@ def attention_verify(
 
     out = pctx.shard(out, "batch", "heads", "seq", "head_dim")
     y = out.transpose(0, 2, 1, 3).reshape(b, w, h * hd)
-    y = linear_apply(params["wo"], y, quant=cfg.quant, training=False, use_pallas=cfg.use_pallas)
+    y = linear_apply(params["wo"], y, quant=cfg.quant, training=False)
     return y, (kt, vt)
 
 
@@ -777,7 +777,7 @@ def attention_decode(
             eff_len = jnp.full((b,), cross_len if cross_len is not None else kt.shape[2], jnp.int32)
             out = decode_attention(qd, kt, vt, eff_len, use_kernel=cfg.use_pallas)
         y = out.reshape(b, 1, h * hd)
-        y = linear_apply(params["wo"], y, quant=cfg.quant, training=False, use_pallas=cfg.use_pallas)
+        y = linear_apply(params["wo"], y, quant=cfg.quant, training=False)
         return y, cache
 
     def attend(qd, starts):
@@ -813,7 +813,7 @@ def _decode_new_token(params, x, lengths, cfg, window, attend_cache):
         out = _merge_new_token(out_c, l_c, m_c, qd, k_new, v_new, sm_scale).astype(x.dtype)
 
     y = out.reshape(b, 1, h * hd)
-    y = linear_apply(params["wo"], y, quant=cfg.quant, training=False, use_pallas=cfg.use_pallas)
+    y = linear_apply(params["wo"], y, quant=cfg.quant, training=False)
     return y, KVCache(k_new, v_new)
 
 

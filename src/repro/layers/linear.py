@@ -49,12 +49,12 @@ def linear_apply(
     quant: QuantConfig,
     *,
     training: bool = False,
-    use_pallas: bool = False,
 ) -> jax.Array:
     w = params["w"]
     if isinstance(w, TernaryWeight):
-        # inference TLMM path (packed 2-bit weights)
-        y = tlmm_matmul(x, w, out_dtype=x.dtype, use_kernel=use_pallas)
+        # inference TLMM path (packed 2-bit weights): the compiled kernel
+        # wherever Mosaic compiles it, the reference on the CPU
+        y = tlmm_matmul(x, w, out_dtype=x.dtype)
     elif quant.ternary:
         if training:
             # BitNet QAT: STE through both weight and activation quantizers

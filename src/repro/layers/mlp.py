@@ -27,7 +27,7 @@ def mlp_init(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> dict:
 
 @jax.named_scope("mlp")
 def mlp_apply(params: dict, x: jax.Array, cfg: ModelConfig, pctx: PartitionCtx, *, training: bool = False) -> jax.Array:
-    kw = dict(quant=cfg.quant, training=training, use_pallas=cfg.use_pallas)
+    kw = dict(quant=cfg.quant, training=training)
     if "w_gate" in params:
         g = linear_apply(params["w_gate"], x, **kw)
         u = linear_apply(params["w_up"], x, **kw)

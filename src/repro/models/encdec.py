@@ -99,7 +99,7 @@ def compute_cross_kv(params, enc_out: jax.Array, cfg: ModelConfig, pctx: Partiti
     hkv, hd = cfg.num_kv_heads, cfg.head_dim
 
     def body(_, lp):
-        kw = dict(quant=cfg.quant, training=False, use_pallas=cfg.use_pallas)
+        kw = dict(quant=cfg.quant, training=False)
         k = linear_apply(lp["cross"]["wk"], enc_out, **kw).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
         v = linear_apply(lp["cross"]["wv"], enc_out, **kw).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
         return None, (k, v)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.tlmm.ops import kernel_serves
 from repro.layers.attention import (
     KVCache,
     attention_decode,
@@ -127,6 +128,17 @@ def linear_residency(cfg: ModelConfig, params: dict) -> Tuple[int, int]:
     latent += sum(layers["moe"][k].shape[0] * layers["moe"][k].shape[1]
                   for k in ("w_gate", "w_up", "w_down") if k in layers.get("moe", {}))
     return packed, latent
+
+
+def kernel_linears(cfg: ModelConfig, params: dict) -> int:
+    """How many of the tree's packed linear matrices ``tlmm_matmul`` serves
+    through the compiled TLMM kernel here in a decode round (0 on the CPU,
+    where the reference serves them)."""
+    if not cfg.quant.ternary:
+        return 0
+    return sum(p["w"].packed.shape[0] for blk in _LINEAR_BLOCKS
+               for p in params["layers"].get(blk, {}).values()
+               if isinstance(p["w"], TernaryWeight) and kernel_serves(p["w"].k, p["w"].n))
 
 
 @jax.named_scope("lm_head")

@@ -77,6 +77,8 @@ _STAT_GAUGES = (
      "Ternary linear matrices served from packed 2-bit weights"),
     ("latent_linears", "repro_engine_latent_linears",
      "Ternary linear matrices re-quantized from latent weights in every call"),
+    ("kernel_linears", "repro_engine_kernel_linears",
+     "Packed linear matrices a decode round runs through the compiled TLMM kernel"),
     ("weight_bytes", "repro_engine_weight_bytes", "Bytes of the resident weights"),
 )
 

@@ -86,7 +86,8 @@ def decode_ternary_slot(packed: jax.Array, i: int) -> jax.Array:
     Elementwise on the byte, so XLA can fuse it into the operand of the
     matmul that consumes it.  The arithmetic stays in 8 bits: XLA on v5e
     fuses this decode into the dot, but writes out an int8 matrix for one
-    that widens to int32 (the Pallas kernel's, which Mosaic needs).
+    that widens to int32.  (The Pallas kernel decodes four bytes per 32-bit
+    word instead: Mosaic has no 8-bit vector arithmetic on v5e.)
     """
     c = (packed >> (2 * i)) & 0x3
     return (c & 1).astype(jnp.int8) - (c >> 1).astype(jnp.int8)

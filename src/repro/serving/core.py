@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels
 from repro.common.tree import tree_bytes
 from repro.configs.base import ModelConfig
 from repro.core.kv_cache import KVSlotManager, insert_prefill_kv
@@ -212,9 +213,11 @@ class EngineStats:
     # weights as the runner holds them, fixed when it is built
     # (ModelRunner.weight_residency): ternary linear matrices served from
     # packed 2-bit weights, those re-quantized from latent weights in every
-    # call, and the bytes of the whole resident weight tree
+    # call, the packed ones a decode round runs through the compiled TLMM
+    # kernel, and the bytes of the whole resident weight tree
     packed_linears: int = 0
     latent_linears: int = 0
+    kernel_linears: int = 0
     weight_bytes: int = 0
 
     def decode_tput(self) -> float:
@@ -253,7 +256,7 @@ class EngineStats:
             "preemptions", "admission_blocks", "replayed_tokens", "t_replay",
             "draft_tokens", "accepted_tokens", "verify_rounds", "slot_rounds",
             "decode_ctx_tokens", "aborts", "sheds", "steps", "t_step", "t_wait",
-            "packed_linears", "latent_linears", "weight_bytes",
+            "packed_linears", "latent_linears", "kernel_linears", "weight_bytes",
         )
         snap = {k: getattr(self, k) for k in counters}
         snap.update(
@@ -344,8 +347,10 @@ class ModelRunner:
                 params, self.engine.param_shardings(jax.eval_shape(lambda: params)))
         self.params = params
         packed, latent = T.linear_residency(cfg, params)
+        with kernels.partitioned(self.engine.spmd):
+            served = T.kernel_linears(cfg, params)
         self.weight_residency = dict(packed_linears=packed, latent_linears=latent,
-                                     weight_bytes=tree_bytes(params))
+                                     kernel_linears=served, weight_bytes=tree_bytes(params))
         self.api = get_model(cfg)
         self.mode = mode
         self.cache_layout = cache_layout

@@ -45,11 +45,14 @@ def test_runner_holds_packed_weights_and_reports_them(tiny):
     n = 7 * cfg.num_layers
     for stats in (eng.stats.snapshot(), eng.snapshot()):
         assert (stats["packed_linears"], stats["latent_linears"]) == (n, 0)
+        assert stats["kernel_linears"] == 0
         assert stats["weight_bytes"] > 0
     eng.reset_stats()
     assert (eng.stats.packed_linears, eng.stats.latent_linears) == (n, 0)
     text = engine_registry(eng).prometheus_text()
     assert re.search(rf"^repro_engine_packed_linears {n}(\.0)?$", text, re.M)
+    # the CPU serves them through the reference, not the compiled kernel
+    assert re.search(r"^repro_engine_kernel_linears 0(\.0)?$", text, re.M)
     assert re.search(r"^repro_engine_latent_linears 0(\.0)?$", text, re.M)
     wb = float(re.search(r"^repro_engine_weight_bytes (\S+)$", text, re.M).group(1))
     assert wb == eng.stats.weight_bytes
@@ -65,7 +68,7 @@ def test_bf16_engine_holds_no_ternary_weights():
     eng = EngineCore(cfg, params, n_slots=2, max_len=MAX_LEN, prompt_len=PROMPT)
     assert eng.runner.params is params
     snap = eng.stats.snapshot()
-    assert (snap["packed_linears"], snap["latent_linears"]) == (0, 0)
+    assert (snap["packed_linears"], snap["latent_linears"], snap["kernel_linears"]) == (0, 0, 0)
 
 
 def _weight_quant_f32_shapes(cfg, params_abstract):
@@ -140,3 +143,34 @@ def test_engine_greedy_equals_model_on_converted_weights(tiny, layout, kv_dtype)
     served = np.stack([eng.finished[f"r{i}"].out_tokens for i in range(2)])
     want = _direct_greedy(cfg, T.convert_for_inference(cfg, params), prompts, max_new, kv_dtype)
     np.testing.assert_array_equal(served, want)
+
+
+def test_kernel_linears_counts_what_the_kernel_serves(tiny, monkeypatch):
+    """0 on the CPU; every packed matrix where Mosaic compiles the kernel
+    (the backend steered as a TPU's here); none in a program that GSPMD
+    partitions over several devices."""
+    import repro.kernels as kernels
+
+    cfg, params = tiny
+    conv = T.convert_for_inference(cfg, params)
+    n = 7 * cfg.num_layers
+    assert T.kernel_linears(cfg, conv) == 0
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    assert T.kernel_linears(cfg, conv) == n
+    assert T.kernel_linears(cfg, params) == 0  # latent weights: no packed matrix
+    with kernels.partitioned():
+        assert T.kernel_linears(cfg, conv) == 0
+    bf16 = reduced_config("qwen2.5-14b", num_layers=2, d_model=64, vocab_size=256)
+    assert T.kernel_linears(bf16, get_model(bf16).init(bf16, jax.random.PRNGKey(0))) == 0
+
+
+def test_linear_apply_takes_no_kernel_switch():
+    import inspect
+
+    from repro.layers.linear import linear_apply
+
+    assert "use_pallas" not in inspect.signature(linear_apply).parameters
+    w = {"w": jnp.ones((8, 4), jnp.float32)}
+    with pytest.raises(TypeError):
+        linear_apply(w, jnp.ones((2, 8), jnp.float32), reduced_config("bitnet-730m").quant,
+                     use_pallas=True)

@@ -35,7 +35,7 @@ def _mk(m, k, n, seed=0):
         (16, 256, 128, 8, 128, 64),
         (32, 512, 256, 16, 128, 128),
         (128, 1024, 512, 128, 256, 512),
-        (8, 128, 384, 8, 128, 32),  # bn not dividing n exercises ops fallback
+        (8, 128, 384, 8, 128, 32),  # N / 4 is no multiple of 128: the wrapper picks 128
     ],
 )
 def test_kernel_matches_reference_shapes(m, k, n, bm, bn, bk):
@@ -46,8 +46,8 @@ def test_kernel_matches_reference_shapes(m, k, n, bm, bn, bk):
     if n % bn == 0 and k % bk == 0 and m % bm == 0:
         out = tlmm_pallas(x_q, tw.packed, scale, bm=bm, bn=bn, bk=bk, out_dtype=jnp.float32, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6)
-    out2 = tlmm_matmul(x, tw, use_kernel=True, out_dtype=jnp.float32,
-                       block_m=bm, block_n=bn, block_k=bk)
+    # the wrapper, with the blocks it picks from the shape
+    out2 = tlmm_matmul(x, tw, use_kernel=True, out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), rtol=1e-6, atol=1e-6)
 
 
@@ -212,3 +212,83 @@ def test_moe_experts_stay_latent_and_are_counted():
     experts = 3 * cfg.num_layers * cfg.num_experts
     assert linear_residency(cfg, params) == (0, 4 * cfg.num_layers + experts)
     assert linear_residency(cfg, conv) == (4 * cfg.num_layers, experts)
+
+
+# --- the serving path: the kernel with the tiles chosen from the shape ---
+
+def _ternary(k, n, seed, scale=0.37):
+    rng = np.random.default_rng(seed)
+    w_q = jnp.asarray(rng.integers(-1, 2, size=(k, n)), jnp.int8)
+    return w_q, TernaryWeight(pack_ternary(w_q), jnp.float32(scale))
+
+
+@pytest.mark.parametrize("k,n", [(1536, 1536), (1536, 4096), (4096, 1536)])
+@pytest.mark.parametrize("m", [1, 4, 12])
+def test_decode_tiles_are_bit_exact_at_bitnet_shapes(m, k, n):
+    from repro.kernels.tlmm.ops import tlmm_tiles
+
+    w_q, tw = _ternary(k, n, seed=m + k + n)
+    bm, bn, bk = tlmm_tiles(m, k, n)
+    # a decode call: all rows in one block, the whole of K, four weight tiles
+    assert (bm, bk) == (m, k) and n // bn == 4
+    x = jnp.asarray(np.random.default_rng(m).normal(size=(m, k)), jnp.float32)
+    x_q, s = quantize_activations_int8(x)
+    # int32 sums: a unit scale and a float32 output hold them exactly
+    acc = jax.lax.dot_general(x_q, w_q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    sums = tlmm_pallas(x_q, tw.packed, jnp.ones((m, 1), jnp.float32), bm=bm, bn=bn, bk=bk,
+                       out_dtype=jnp.float32, interpret=True)
+    np.testing.assert_array_equal(np.asarray(sums), np.asarray(acc, np.float32))
+    scale = s * tw.scale
+    for out_dtype in (jnp.float32, jnp.bfloat16):
+        want = tlmm_reference(x_q, tw.packed, scale, out_dtype=out_dtype)
+        got = tlmm_matmul(x, tw, use_kernel=True, out_dtype=out_dtype)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+def test_layer_stacked_weight_in_a_scan_matches_reference():
+    layers, m, k, n = 3, 4, 256, 512
+    packed, scales = [], []
+    for l in range(layers):
+        _, tw = _ternary(k, n, seed=l, scale=0.1 * (l + 1))
+        packed.append(tw.packed)
+        scales.append(tw.scale)
+    stack = TernaryWeight(jnp.stack(packed), jnp.stack(scales))
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(m, k)), jnp.float32)
+
+    def run(use_kernel):
+        def body(h, w):
+            y = tlmm_matmul(h, w, out_dtype=jnp.float32, use_kernel=use_kernel)
+            return h, y
+        return jax.jit(lambda s: jax.lax.scan(body, x, s)[1])(stack)
+
+    got, want = run(True), run(False)
+    assert got.shape == (layers, m, n)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # each step reads its own layer of the stack
+    for l in range(layers):
+        one = TernaryWeight(stack.packed[l], stack.scale[l])
+        np.testing.assert_allclose(np.asarray(got[l]), np.asarray(
+            tlmm_matmul(x, one, out_dtype=jnp.float32, use_kernel=False)), rtol=1e-6)
+
+
+def _runs_kernel(x, tw) -> bool:
+    jaxpr = jax.make_jaxpr(lambda x: tlmm_matmul(x, tw))(x)
+    return "pallas_call" in str(jaxpr)
+
+
+def test_path_follows_the_platform(monkeypatch):
+    import repro.kernels as kernels
+
+    _, tw = _ternary(256, 512, seed=1)
+    x = jnp.ones((4, 256), jnp.float32)
+    assert not kernels.compiled_kernels()
+    assert not _runs_kernel(x, tw)  # the CPU: the reference
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)  # as on a TPU
+    assert kernels.compiled_kernels() and _runs_kernel(x, tw)
+    with kernels.partitioned():  # GSPMD cannot partition a Mosaic kernel
+        assert not kernels.compiled_kernels() and not _runs_kernel(x, tw)
+    assert _runs_kernel(x, tw)
+    # a shape the kernel cannot tile takes the reference
+    _, odd = _ternary(24, 128, seed=2)
+    assert not _runs_kernel(jnp.ones((4, 24), jnp.float32), odd)

@@ -102,3 +102,39 @@ def test_tlmm_compiles(one_chip, m):
     _assert_kernel(_compile(
         one_chip, lambda x, w, s: tlmm_pallas(x, w, s, bm=min(m, 128), bn=128, bk=512),
         ((m, k), jnp.int8), ((k // 4, n), jnp.uint8), ((m, 1), jnp.float32)))
+
+
+@pytest.mark.parametrize("k,n", [(CFG.d_model, CFG.d_model), (CFG.d_model, CFG.d_ff),
+                                 (CFG.d_ff, CFG.d_model)])
+@pytest.mark.parametrize("m", [4, 512])
+def test_tlmm_serving_tiles_compile(one_chip, m, k, n):
+    """The blocks ``tlmm_matmul`` picks for a decode round (4 rows) and a
+    prefill chunk (512) fit the chip's VMEM at bitnet-730m's matrices."""
+    from repro.kernels.tlmm.ops import tlmm_tiles
+
+    bm, bn, bk = tlmm_tiles(m, k, n)
+    _assert_kernel(_compile(
+        one_chip, lambda x, w, s: tlmm_pallas(x, w, s, bm=bm, bn=bn, bk=bk),
+        ((m, k), jnp.int8), ((k // 4, n), jnp.uint8), ((m, 1), jnp.float32)))
+
+
+def test_tlmm_reads_the_layer_stack_in_place(one_chip):
+    """In a layer scan the kernel's weight operand is the scan's slice of
+    the (L, K/4, N) stack: the slice fuses into the kernel's operand, so no
+    layer's packed matrix is copied out for the call."""
+    from repro.kernels.tlmm.ops import tlmm_tiles
+
+    layers, k, n = CFG.num_layers, CFG.d_model, CFG.d_ff
+    bm, bn, bk = tlmm_tiles(4, k, n)
+
+    def scan(x, stack, s):
+        def body(acc, w):
+            return acc + tlmm_pallas(x, w, s, bm=bm, bn=bn, bk=bk, out_dtype=jnp.float32), None
+        return jax.lax.scan(body, jnp.zeros((4, n), jnp.float32), stack)[0]
+
+    text = _compile(one_chip, scan, ((4, k), jnp.int8), ((layers, k // 4, n), jnp.uint8),
+                    ((4, 1), jnp.float32)).as_text()
+    comps = text.split("\n\n")
+    calls = [c for c in comps if 'custom_call_target="tpu_custom_call"' in c]
+    assert calls and all(f"u8[{layers},{k // 4},{n}]" in c for c in calls)
+    assert "kind=kCustom" in text
